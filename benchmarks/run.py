@@ -16,6 +16,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from benchmarks import paper_benches as pb            # noqa: E402
 from benchmarks.roofline import bench_roofline        # noqa: E402
 from benchmarks.trace_replay import bench_trace_replay  # noqa: E402
+from repro.kernels.mode import use_compile_cache      # noqa: E402
 
 BENCHES = [
     ("table1", pb.bench_table1_workload_mix),
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     for name, fn in BENCHES:

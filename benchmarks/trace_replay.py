@@ -47,6 +47,7 @@ from repro.core.cluster_sim import BatchedHopsFSSim, profile_ops
 from repro.core.workload import (NamespaceSpec, SPOTIFY_TRACE_MIX,
                                  SyntheticNamespace, TraceReplay,
                                  WRITE_HEAVY_MIX, make_spotify_trace)
+from repro.kernels.mode import use_compile_cache
 
 Row = Tuple[str, float, str]
 
@@ -1001,6 +1002,7 @@ def main() -> None:
                          "regenerate one section without touching the "
                          "committed artifact)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.only == "big_dir":
         t0 = time.time()

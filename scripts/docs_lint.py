@@ -206,6 +206,7 @@ def check_doc(path: Path) -> list:
             stem = tok[:-3]
             if any(_import(base + stem) for base in IMPORT_BASES
                    if base != "" or stem in REPO_ROOTS) \
+                    or (ROOT / tok).exists() \
                     or list(ROOT.glob(f"*/{tok}")) \
                     or list(ROOT.glob(f"*/**/{tok}")):
                 continue

@@ -59,7 +59,7 @@ from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 from .columnar import lower_trace_fused, validate_window_pks
 from .hint_cache import InodeHintCache, absorb_response
 from .namenode import (NamenodeCluster, OpOutcome, PipelineStats, PlanHint,
-                       RequestPipeline)
+                       RequestPipeline, _KernelProbe, _with_phash_kernel)
 from .ops_registry import REGISTRY, WorkloadOp
 from .store import StoreError
 from .tables import split_path
@@ -68,6 +68,10 @@ from .workload import ColumnarTrace
 __all__ = ["BatchPlanner", "HintResolver", "MultiCacheResolver",
            "PlannedBatch", "PlannedRequestPipeline", "PlanReport",
            "WindowController"]
+
+# the planner's fused chain hash has its own gate and counters, apart
+# from the namenodes' single-key phash batches
+_phash_chain_probe = _KernelProbe()
 
 
 class MultiCacheResolver:
@@ -276,11 +280,10 @@ class PlanReport:
 def _chain_partitions(ct: ColumnarTrace, n_partitions: int
                       ) -> Tuple[Any, Any, Any, bool]:
     """One fused kernel launch for the whole window; the numpy oracle for
-    small windows or while the Pallas stack is unavailable — same shared
-    probe + size gate + fallback policy as the namenodes' own
-    ``_partitions_for`` (identical results either way)."""
-    from .namenode import _with_phash_kernel
-
+    small windows or (on the CPU) while the kernel stack is unavailable —
+    the same size gate + fallback policy as the namenodes' own
+    ``_partitions_for``, under its own probe (identical results either
+    way)."""
     def kern():
         from ..kernels.phash.ops import phash_chains
         return phash_chains(ct.parent_ids, ct.name_hashes, ct.hint_ids,
@@ -292,7 +295,7 @@ def _chain_partitions(ct: ColumnarTrace, n_partitions: int
                                ct.depths, n_partitions)
 
     (comp, hint_parts, sigs), used_kernel = _with_phash_kernel(
-        kern, fallback, n_keys=ct.n)
+        kern, fallback, n_keys=ct.n, probe=_phash_chain_probe)
     return comp, hint_parts, sigs, used_kernel
 
 

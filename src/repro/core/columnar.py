@@ -8,7 +8,7 @@ lease) out column-major: every column is one flat array/list indexed by a
 row *slot*, integer id columns and the per-row partition assignment are
 mirrored into flat numpy arrays, and the inode table's composite PK
 ``(parent_id, name)`` is additionally maintained in an open-addressing
-:class:`HashIndex` whose backing arrays feed the fused Pallas kernels:
+:class:`HashIndex` whose backing arrays feed the fused device kernels:
 
 * ``repro.kernels.pkval`` — grouped-batch PK validation: ONE launch
   checks a whole planner window's client-resolved ``(parent_id, name)``
@@ -578,8 +578,8 @@ def _snapshot_resolver(cache: Any, fallback: Any
 
 def lower_trace_fused(wops: Sequence[WorkloadOp], resolver: Any, *,
                       max_depth: int = 16,
-                      min_batch: Optional[int] = None,
-                      interpret: bool = True) -> Tuple[ColumnarTrace, bool]:
+                      min_batch: Optional[int] = None
+                      ) -> Tuple[ColumnarTrace, bool]:
     """``workload.lower_trace`` with the per-probe Python loop replaced by
     ONE ``hintchain`` kernel launch over the whole window.
 
@@ -590,7 +590,8 @@ def lower_trace_fused(wops: Sequence[WorkloadOp], resolver: Any, *,
     path.  Windows below ``min_batch`` total probes, resolvers that are
     not a ``HintResolver`` shape, or an unavailable kernel stack all fall
     back — the pure walk for the first two, the numpy oracle under the
-    ``_KernelProbe`` gate for the last."""
+    ``_KernelProbe`` gate for the last (on the CPU only: where kernels run
+    compiled, a kernel failure propagates)."""
     if min_batch is None:
         min_batch = HINTCHAIN_MIN_BATCH      # runtime lookup: patchable
     cache = getattr(resolver, "cache", None)
@@ -627,7 +628,7 @@ def lower_trace_fused(wops: Sequence[WorkloadOp], resolver: Any, *,
     def kern() -> Tuple[np.ndarray, np.ndarray]:
         from ..kernels.hintchain.ops import hintchain_resolve
         return hintchain_resolve(cidx.arrays(), fidx.arrays(), nam, dep,
-                                 root_id=ROOT_ID, interpret=interpret)
+                                 root_id=ROOT_ID)
 
     def fallb() -> Tuple[np.ndarray, np.ndarray]:
         from ..kernels.hintchain.ref import hintchain_ref
@@ -640,9 +641,10 @@ def lower_trace_fused(wops: Sequence[WorkloadOp], resolver: Any, *,
         (childs, srcs), used = _with_phash_kernel(
             kern, fallb, n_keys=total, min_batch=min_batch,
             probe=_hintchain_probe)
-    except Exception:
+    except ImportError:
         # even the numpy oracle failed (kernel package unimportable):
-        # the pure walk is always available
+        # the pure walk is always available, but the window is counted
+        _hintchain_probe.demotions += 1
         return lower_trace(wops, resolver, max_depth=max_depth), False
 
     type_names = list(REGISTRY.names())
@@ -748,8 +750,7 @@ def _chain_probes(chains: Sequence[Tuple[Sequence[Tuple[int, str]],
 def _validate_chains(hindex: HashIndex,
                      chains: Sequence[Tuple[Sequence[Tuple[int, str]],
                                             Optional[int]]],
-                     *, min_batch: int, interpret: bool
-                     ) -> Tuple[Set[int], int, bool]:
+                     *, min_batch: int) -> Tuple[Set[int], int, bool]:
     """(chain indices whose client resolution disagrees with the store,
     probe count, used_kernel). AMBIG buckets are inconclusive — the chain
     is KEPT and the server-side in-transaction validation decides."""
@@ -764,7 +765,7 @@ def _validate_chains(hindex: HashIndex,
     def kern() -> np.ndarray:
         from ..kernels.pkval.ops import pkval_lookup
         tp, tn, tv = hindex.arrays()
-        return pkval_lookup(tp, tn, tv, parents, nams, interpret=interpret)
+        return pkval_lookup(tp, tn, tv, parents, nams)
 
     def fallb() -> np.ndarray:
         from ..kernels.pkval.ref import pkval_ref
@@ -785,8 +786,7 @@ def _validate_chains(hindex: HashIndex,
 
 
 def validate_window_pks(store: MetadataStore, ct: ColumnarTrace, *,
-                        min_batch: Optional[int] = None,
-                        interpret: bool = True
+                        min_batch: Optional[int] = None
                         ) -> Optional[Tuple[Set[int], int, bool]]:
     """Grouped-batch PK validation of a planner window (§5.1 batched
     reads, validated BEFORE they ship): every client-resolved chain in
@@ -817,15 +817,14 @@ def validate_window_pks(store: MetadataStore, ct: ColumnarTrace, *,
     if not chains:
         return set(), 0, False
     demoted_local, probes, used = _validate_chains(
-        hindex, chains, min_batch=min_batch, interpret=interpret)
+        hindex, chains, min_batch=min_batch)
     return {owners[j] for j in demoted_local}, probes, used
 
 
 def prevalidate_chains(store: MetadataStore,
                        chains: Sequence[Tuple[Sequence[Tuple[int, str]],
                                               Optional[int]]],
-                       *, min_batch: Optional[int] = None,
-                       interpret: bool = True
+                       *, min_batch: Optional[int] = None
                        ) -> Optional[Tuple[List[bool], int, bool]]:
     """Namenode-side flavour of :func:`validate_window_pks` for the
     grouped read path: ``chains`` are the hint chains a read run is about
@@ -841,7 +840,7 @@ def prevalidate_chains(store: MetadataStore,
     if hindex is None:
         return None
     demoted, probes, used = _validate_chains(
-        hindex, chains, min_batch=min_batch, interpret=interpret)
+        hindex, chains, min_batch=min_batch)
     return [k not in demoted for k in range(len(chains))], probes, used
 
 
@@ -857,7 +856,7 @@ class WaveExpansion:
     aligned to; ``counts``/``dirs``/``sizes`` are int64 segment sums over
     each member's direct children; ``child_ids``/``child_dir_ids`` the
     children themselves (``child_dir_ids`` is the next frontier); ``used``
-    whether the Pallas kernel ran (False = numpy-oracle fallback, i.e. a
+    whether the device kernel ran (False = numpy-oracle fallback, i.e. a
     demotion above the gate)."""
 
     __slots__ = ("wave", "counts", "dirs", "sizes", "child_ids",
@@ -879,8 +878,8 @@ class WaveExpansion:
 
 
 def expand_wave(store: MetadataStore, wave_ids: Iterable[int], *,
-                min_batch: Optional[int] = None,
-                interpret: bool = True) -> Optional["WaveExpansion"]:
+                min_batch: Optional[int] = None
+                ) -> Optional["WaveExpansion"]:
     """Resolve one subtree BFS wave — every member's direct children plus
     the ``du``/``content_summary`` segment sums — in ONE fused launch over
     the columnar inode table's hot columns.
@@ -913,7 +912,7 @@ def expand_wave(store: MetadataStore, wave_ids: Iterable[int], *,
 
     def kern():
         from ..kernels.treeagg.ops import treeagg_expand
-        return treeagg_expand(wave, par, isdir, size, interpret=interpret)
+        return treeagg_expand(wave, par, isdir, size)
 
     def fallb():
         from ..kernels.treeagg.ref import treeagg_ref

@@ -60,18 +60,24 @@ PHASH_MIN_BATCH = 512
 
 
 class _KernelProbe:
-    """Availability gate for the vectorized phash path.
+    """Availability gate and launch/demotion counters for one kernel family.
 
     A kernel failure disables the vectorized path only TEMPORARILY: after
     ``reprobe_every`` eligible calls the kernel is probed again, so a
     transient failure (jit cache eviction, accelerator hiccup, OOM) can
     never latch the scalar fallback for the life of the process — which is
-    exactly what the module-global bool this replaces used to do."""
+    exactly what the module-global bool this replaces used to do.
+
+    ``launches`` counts calls the kernel served; ``demotions`` counts calls
+    above the size gate that it did not (a failure, or the latched
+    fallback), so a run can prove its device path actually ran."""
 
     def __init__(self, reprobe_every: int = 64):
         self.reprobe_every = reprobe_every
         self.failures = 0                  # consecutive probe failures
         self._calls_since_failure = 0
+        self.launches = 0
+        self.demotions = 0
 
     def usable(self) -> bool:
         if self.failures == 0:
@@ -104,16 +110,27 @@ def _with_phash_kernel(kernel_fn: Any, fallback_fn: Any, *, n_keys: int,
     fallback policy for namenode-side grouping and the client-side batch
     planner — returns (result, used_kernel). Other kernel families (pkval,
     hintchain) pass their own ``probe`` so one family's failure never
-    latches another's fallback."""
+    latches another's fallback.
+
+    The fallback exists for the CPU, where kernels run in the Pallas
+    interpreter. Where they run compiled, a kernel failure is a bug, not
+    a reason to answer from the host oracle: it is counted and re-raised."""
     gate = probe if probe is not None else _phash_probe
-    if n_keys >= max(2, min_batch) and gate.usable():
-        try:
-            out = kernel_fn()
-        except Exception:
-            gate.failed()
-        else:
-            gate.succeeded()
-            return out, True
+    if n_keys >= max(2, min_batch):
+        if gate.usable():
+            try:
+                out = kernel_fn()
+            except Exception:
+                gate.failed()
+                from ..kernels import mode
+                if not mode.interpret():
+                    gate.demotions += 1
+                    raise
+            else:
+                gate.succeeded()
+                gate.launches += 1
+                return out, True
+        gate.demotions += 1
     return fallback_fn(), False
 
 

@@ -367,6 +367,9 @@ class SubtreeOps:
         try:
             exp = expand_wave(self.store, dir_ids)
         except Exception:                    # pragma: no cover - advisory
+            # the transactional scans stay authoritative, but a launch
+            # that failed is counted, never skipped in silence
+            self.treeagg_demotions += 1
             return None
         if exp is None:
             return None

@@ -1,6 +1,10 @@
-"""Pallas TPU kernels for the compute hot-spots (+ the metadata-plane
-partition hash). Each subpackage: kernel.py (pl.pallas_call + BlockSpec),
-ops.py (jit'd wrapper + custom_vjp), ref.py (pure-jnp oracle).
+"""Device kernels for the compute hot-spots. Each subpackage: kernel.py
+(the kernel), ops.py (jit'd wrapper, padding, custom_vjp), ref.py
+(oracle).
 
-TPU is the TARGET; this container validates via interpret=True.
+The metadata path's kernels: ``phash`` (Pallas), and ``pkval``,
+``hintchain`` and ``treeagg`` (XLA programs: their random lookups into
+HBM-resident tables do not lower through Mosaic). :mod:`.mode` picks how
+a Pallas kernel runs from the backend — compiled on the TPU, interpreted
+on the CPU where the tests run — and places the compile cache.
 """

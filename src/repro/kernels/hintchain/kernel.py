@@ -1,4 +1,4 @@
-"""Vectorized hint-chain resolution (HopsFS §5.1 inode hint cache) — Pallas.
+"""Vectorized hint-chain resolution (HopsFS §5.1 inode hint cache) — XLA.
 
 The client-side batch planner resolves every op's path against its hint
 view: the client's own response-warmed cache first, the merged namenode
@@ -7,7 +7,7 @@ Python loop probes one ``(parent_id, name)`` per step, per op.  This
 kernel walks ALL chains of a planner window at once: both cache views are
 snapshotted into open-addressing hash tables (``repro.core.columnar.
 HashIndex``) and the kernel advances every op's parent pointer one depth
-per unrolled step — each step probing the client table, then the fallback
+per scan step — each step probing the client table, then the fallback
 table, exactly the resolver's precedence.
 
 Output encoding per (op, depth):
@@ -18,62 +18,33 @@ Output encoding per (op, depth):
   child == -3  collided bucket — the host must re-resolve this op through
                the exact per-probe Python walk (names, not 32-bit hashes)
 
-Grid: 1-D over op blocks; both snapshot tables broadcast whole per block.
+Like ``pkval``, whose lookup each depth step reuses, this is a plain XLA
+program: the probes are random gathers into HBM-resident tables.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from ..pkval.kernel import MAX_PROBE, _bucket_hash
-
-
-def _probe_table(tp, tn, tv, cap: int, max_probe: int, par, nam):
-    """One linear-probe lookup of every op's current (parent, name-hash)
-    against one snapshot table; -1 = miss, passes -3 buckets through."""
-    slot = _bucket_hash(par, nam) & jnp.uint32(cap - 1)
-
-    # rolled probe loop — see pkval.kernel: unrolled gather chains make
-    # XLA compile time explode; fori_loop keeps the graph O(1) in depth
-    def _step(step, carry):
-        val, alive = carry
-        j = ((slot + step.astype(jnp.uint32)) & jnp.uint32(cap - 1)) \
-            .astype(jnp.int32)
-        ep = jnp.take(tp, j)
-        en = jnp.take(tn, j)
-        ev = jnp.take(tv, j)
-        hit = alive & (ep >= 0) & (ep == par) & (en == nam)
-        val = jnp.where(hit, ev, val)
-        alive = alive & ~hit & (ep != jnp.int32(-1))
-        return val, alive
-
-    val = jnp.full(par.shape, -1, jnp.int32)
-    alive = par >= 0
-    val, _ = jax.lax.fori_loop(0, max_probe, _step, (val, alive))
-    return val
+from ..pkval.kernel import MAX_PROBE, pkval
 
 
-def _hintchain_kernel(cp_ref, cn_ref, cv_ref, fp_ref, fn_ref, fv_ref,
-                      nam_ref, dep_ref, child_ref, src_ref, *,
-                      depth: int, ccap: int, fcap: int, root_id: int,
-                      max_probe: int):
-    cp, cn, cv = cp_ref[...], cn_ref[...], cv_ref[...]
-    fp, fn, fv = fp_ref[...], fn_ref[...], fv_ref[...]
-    nam = nam_ref[...]                       # [bn, depth] uint32
-    dep = dep_ref[...]                       # [bn] int32 (0 = dead op)
+def hintchain(cp: jax.Array, cn: jax.Array, cv: jax.Array, fp: jax.Array,
+              fn: jax.Array, fv: jax.Array, name_hashes: jax.Array,
+              depths: jax.Array, *, root_id: int = 1,
+              max_probe: int = MAX_PROBE):
+    """client table [Cc] x fallback table [Cf] x chains [N, D] ->
+    (child_ids [N, D] int32, src [N, D] int32)."""
+    N, D = name_hashes.shape
 
     # rolled depth loop: compile time is independent of the chain-depth
-    # bound (an unrolled depth x probe x 2-table gather chain previously
-    # took minutes to compile even in interpret mode)
-    def _depth(d, carry):
-        parent, alive, childs, srcs = carry
-        probing = alive & (d < dep)
-        nd = jax.lax.dynamic_index_in_dim(nam, d, axis=1, keepdims=False)
-        cval = _probe_table(cp, cn, cv, ccap, max_probe, parent, nd)
-        fval = _probe_table(fp, fn, fv, fcap, max_probe, parent, nd)
+    # bound; the scan walks depth-major rows of the transposed chains
+    def _depth(carry, xs):
+        parent, alive = carry
+        d, nd = xs
+        probing = alive & (d < depths)
+        cval = pkval(cp, cn, cv, parent, nd, max_probe=max_probe)
+        fval = pkval(fp, fn, fv, parent, nd, max_probe=max_probe)
         # resolver precedence: any client answer (including a collided
         # bucket — the Python walk might have resolved it) wins
         val = jnp.where(cval != jnp.int32(-1), cval, fval)
@@ -81,50 +52,13 @@ def _hintchain_kernel(cp_ref, cn_ref, cv_ref, fp_ref, fn_ref, fv_ref,
         child_d = jnp.where(probing, val, jnp.int32(-2))
         src_d = jnp.where(found & (cval > 0), jnp.int32(0),
                           jnp.where(found, jnp.int32(1), jnp.int32(-1)))
-        childs = jax.lax.dynamic_update_index_in_dim(childs, child_d, d,
-                                                     axis=1)
-        srcs = jax.lax.dynamic_update_index_in_dim(srcs, src_d, d, axis=1)
         parent = jnp.where(found, val, parent)
         alive = alive & found
-        return parent, alive, childs, srcs
+        return (parent, alive), (child_d, src_d)
 
-    parent = jnp.full(dep.shape, root_id, jnp.int32)
-    alive = dep > 0
-    childs = jnp.full(nam.shape, -2, jnp.int32)
-    srcs = jnp.full(nam.shape, -1, jnp.int32)
-    _, _, childs, srcs = jax.lax.fori_loop(
-        0, depth, _depth, (parent, alive, childs, srcs))
-    child_ref[...] = childs
-    src_ref[...] = srcs
-
-
-def hintchain(cp: jax.Array, cn: jax.Array, cv: jax.Array, fp: jax.Array,
-              fn: jax.Array, fv: jax.Array, name_hashes: jax.Array,
-              depths: jax.Array, *, root_id: int = 1, block_n: int = 1024,
-              max_probe: int = MAX_PROBE, interpret: bool = True):
-    """client table [Cc] x fallback table [Cf] x chains [N, D] ->
-    (child_ids [N, D] int32, src [N, D] int32)."""
-    N, D = name_hashes.shape
-    (Cc,) = cp.shape
-    (Cf,) = fp.shape
-    bn = min(block_n, N)
-    kernel = functools.partial(_hintchain_kernel, depth=D, ccap=Cc,
-                               fcap=Cf, root_id=root_id,
-                               max_probe=max_probe)
-    return pl.pallas_call(
-        kernel,
-        grid=(N // bn,),
-        in_specs=[pl.BlockSpec((Cc,), lambda i: (0,)),
-                  pl.BlockSpec((Cc,), lambda i: (0,)),
-                  pl.BlockSpec((Cc,), lambda i: (0,)),
-                  pl.BlockSpec((Cf,), lambda i: (0,)),
-                  pl.BlockSpec((Cf,), lambda i: (0,)),
-                  pl.BlockSpec((Cf,), lambda i: (0,)),
-                  pl.BlockSpec((bn, D), lambda i: (i, 0)),
-                  pl.BlockSpec((bn,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((bn, D), lambda i: (i, 0)),
-                   pl.BlockSpec((bn, D), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((N, D), jnp.int32),
-                   jax.ShapeDtypeStruct((N, D), jnp.int32)],
-        interpret=interpret,
-    )(cp, cn, cv, fp, fn, fv, name_hashes, depths)
+    parent = jnp.full((N,), root_id, jnp.int32)
+    alive = depths > 0
+    _, (childs, srcs) = jax.lax.scan(
+        _depth, (parent, alive),
+        (jnp.arange(D, dtype=jnp.int32), name_hashes.T))
+    return childs.T, srcs.T

@@ -1,6 +1,4 @@
 """jit'd wrapper + padding for the hint-chain resolution kernel."""
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,20 +7,11 @@ from ..phash.ops import _pad_pow2
 from ..pkval.kernel import MAX_PROBE
 from .kernel import hintchain as _hintchain
 
-
-@functools.partial(jax.jit,
-                   static_argnames=("root_id", "max_probe", "interpret"))
-def hintchain(cp, cn, cv, fp, fn, fv, name_hashes, depths,
-              root_id: int = 1, max_probe: int = MAX_PROBE,
-              interpret: bool = True):
-    return _hintchain(cp, cn, cv, fp, fn, fv, name_hashes, depths,
-                      root_id=root_id, max_probe=max_probe,
-                      interpret=interpret)
+hintchain = jax.jit(_hintchain, static_argnames=("root_id", "max_probe"))
 
 
 def hintchain_resolve(client_idx, fallback_idx, name_hashes, depths, *,
-                      root_id: int = 1, max_probe: int = MAX_PROBE,
-                      interpret: bool = True
+                      root_id: int = 1, max_probe: int = MAX_PROBE
                       ) -> "tuple[np.ndarray, np.ndarray]":
     """Resolve a whole window's hint chains in ONE kernel launch.
 
@@ -30,7 +19,7 @@ def hintchain_resolve(client_idx, fallback_idx, name_hashes, depths, *,
     triples — ``HashIndex.arrays()`` snapshots of the client cache and the
     merged namenode caches.  ``name_hashes [N, D]`` / ``depths [N]``
     describe every op's component chain (depth 0 = never probed).  N is
-    padded to a power of two so the 1-D grid tiles evenly.  Returns the
+    padded to a power of two so jit recompiles stay O(log N).  Returns the
     kernel's (child_ids, src) [N, D] encoding (see kernel module doc)."""
     nam = np.asarray(name_hashes, dtype=np.int64) & 0xFFFFFFFF
     dep = np.asarray(depths, dtype=np.int32)
@@ -52,5 +41,5 @@ def hintchain_resolve(client_idx, fallback_idx, name_hashes, depths, *,
         jnp.asarray(cv.astype(np.int32)), jnp.asarray(fp.astype(np.int32)),
         jnp.asarray(fn_.astype(np.uint32)), jnp.asarray(fv.astype(np.int32)),
         jnp.asarray(nbuf), jnp.asarray(dbuf), root_id=root_id,
-        max_probe=max_probe, interpret=interpret)
+        max_probe=max_probe)
     return np.asarray(childs)[:n], np.asarray(srcs)[:n]

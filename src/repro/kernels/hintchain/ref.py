@@ -2,23 +2,7 @@
 import numpy as np
 
 from ..pkval.kernel import MAX_PROBE
-from ..pkval.ref import bucket_hash_ref
-
-
-def _probe_table_ref(tp, tn, tv, par, nam, max_probe):
-    cap = tp.shape[0]
-    slot = bucket_hash_ref(par, nam) & np.uint32(cap - 1)
-    val = np.full(par.shape, -1, np.int32)
-    alive = par >= 0
-    with np.errstate(over="ignore"):
-        for step in range(max_probe):
-            j = ((slot + np.uint32(step)) & np.uint32(cap - 1)) \
-                .astype(np.int64)
-            ep, en, ev = tp[j], tn[j], tv[j]
-            hit = alive & (ep >= 0) & (ep == par) & (en == nam)
-            val = np.where(hit, ev, val)
-            alive = alive & ~hit & (ep != np.int32(-1))
-    return val
+from ..pkval.ref import pkval_ref
 
 
 def hintchain_ref(cp, cn, cv, fp, fn, fv, name_hashes, depths, *,
@@ -40,8 +24,8 @@ def hintchain_ref(cp, cn, cv, fp, fn, fv, name_hashes, depths, *,
     for d in range(d_max):
         probing = alive & (np.int32(d) < dep)
         nd = nam[:, d]
-        cval = _probe_table_ref(cp, cn, cv, parent, nd, max_probe)
-        fval = _probe_table_ref(fp, fn, fv, parent, nd, max_probe)
+        cval = pkval_ref(cp, cn, cv, parent, nd, max_probe=max_probe)
+        fval = pkval_ref(fp, fn, fv, parent, nd, max_probe=max_probe)
         val = np.where(cval != np.int32(-1), cval, fval)
         found = probing & (val > 0)
         childs[:, d] = np.where(probing, val, np.int32(-2))

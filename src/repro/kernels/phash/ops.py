@@ -5,24 +5,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import mode
 from .kernel import phash as _phash
 from .kernel import phash_chain as _phash_chain
 
 
-@functools.partial(jax.jit, static_argnames=("n_partitions", "interpret"))
-def phash(keys, n_partitions: int = 64, interpret: bool = True):
-    return _phash(keys, n_partitions=n_partitions, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("n_partitions",))
+def phash(keys, n_partitions: int = 64):
+    return _phash(keys, n_partitions=n_partitions,
+                  interpret=mode.interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("n_partitions", "interpret"))
-def phash_chain(parents, names, hints, depths, n_partitions: int = 64,
-                interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("n_partitions",))
+def phash_chain(parents, names, hints, depths, n_partitions: int = 64):
     return _phash_chain(parents, names, hints, depths,
-                        n_partitions=n_partitions, interpret=interpret)
+                        n_partitions=n_partitions,
+                        interpret=mode.interpret())
 
 
-def phash_partitions(keys, n_partitions: int = 64, *,
-                     interpret: bool = True) -> np.ndarray:
+def phash_partitions(keys, n_partitions: int = 64) -> np.ndarray:
     """Partition ids for a whole batch of integer keys at once.
 
     This is the vectorized path->partition step of the batched request
@@ -43,8 +44,7 @@ def phash_partitions(keys, n_partitions: int = 64, *,
         padded *= 2
     buf = np.zeros(padded, dtype=np.uint32)
     buf[:n] = arr.astype(np.uint32)
-    out = phash(jnp.asarray(buf), n_partitions=n_partitions,
-                interpret=interpret)
+    out = phash(jnp.asarray(buf), n_partitions=n_partitions)
     return np.asarray(out)[:n]
 
 
@@ -56,7 +56,7 @@ def _pad_pow2(n: int, floor: int = 8) -> int:
 
 
 def phash_chains(parent_ids, name_hashes, hint_ids, depths,
-                 n_partitions: int = 64, *, interpret: bool = True
+                 n_partitions: int = 64
                  ) -> "tuple[np.ndarray, np.ndarray, np.ndarray]":
     """Fused chain hashing for the client-side batch planner: ONE kernel
     launch over every path's (parent_id, name) component chain returns
@@ -71,7 +71,8 @@ def phash_chains(parent_ids, name_hashes, hint_ids, depths,
 
     ``parent_ids``/``name_hashes`` are [N, D] arrays padded with zeros
     beyond ``depths[n]`` components. N is padded to a power of two (>= 8)
-    so the 1-D grid tiles evenly and jit recompiles stay O(log N)."""
+    so the 1-D grid tiles evenly and jit recompiles stay O(log N); the
+    kernel takes the chains depth-major, so they are transposed here."""
     par = np.asarray(parent_ids, dtype=np.int64) & 0xFFFFFFFF
     nam = np.asarray(name_hashes, dtype=np.int64) & 0xFFFFFFFF
     hin = np.asarray(hint_ids, dtype=np.int64) & 0xFFFFFFFF
@@ -83,15 +84,16 @@ def phash_chains(parent_ids, name_hashes, hint_ids, depths,
                 np.zeros(0, np.uint32))
     d = max(1, par.shape[1])
     pn = _pad_pow2(n)
-    bufs = [np.zeros((pn, d), np.uint32), np.zeros((pn, d), np.uint32)]
-    bufs[0][:n, :par.shape[1]] = par.astype(np.uint32)
-    bufs[1][:n, :nam.shape[1]] = nam.astype(np.uint32)
-    hbuf = np.zeros(pn, np.uint32)
-    hbuf[:n] = hin.astype(np.uint32)
-    dbuf = np.zeros(pn, np.int32)
-    dbuf[:n] = dep
+    pbuf = np.zeros((d, pn), np.uint32)
+    nbuf = np.zeros((d, pn), np.uint32)
+    pbuf[:par.shape[1], :n] = par.T.astype(np.uint32)
+    nbuf[:nam.shape[1], :n] = nam.T.astype(np.uint32)
+    hbuf = np.zeros((1, pn), np.uint32)
+    hbuf[0, :n] = hin.astype(np.uint32)
+    dbuf = np.zeros((1, pn), np.int32)
+    dbuf[0, :n] = dep
     comp, hint_parts, sigs = phash_chain(
-        jnp.asarray(bufs[0]), jnp.asarray(bufs[1]), jnp.asarray(hbuf),
-        jnp.asarray(dbuf), n_partitions=n_partitions, interpret=interpret)
-    return (np.asarray(comp)[:n], np.asarray(hint_parts)[:n],
-            np.asarray(sigs)[:n])
+        jnp.asarray(pbuf), jnp.asarray(nbuf), jnp.asarray(hbuf),
+        jnp.asarray(dbuf), n_partitions=n_partitions)
+    return (np.asarray(comp)[:, :n].T, np.asarray(hint_parts)[0, :n],
+            np.asarray(sigs)[0, :n])

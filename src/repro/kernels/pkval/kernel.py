@@ -1,4 +1,4 @@
-"""Grouped-batch PK validation (HopsFS §5.1 batched PK reads) — Pallas.
+"""Grouped-batch PK validation (HopsFS §5.1 batched PK reads) — XLA.
 
 The columnar inode table (``repro.core.columnar``) maintains an
 open-addressing hash index over its composite PK ``(parent_id,
@@ -15,16 +15,15 @@ probe chain), ``-2`` = tombstone (probe continues), value ``-3`` = a
 report "cannot validate" rather than a wrong id.  Probe rows with parent
 ``< 0`` are padding and always miss.
 
-Grid: 1-D over probe blocks; the index arrays are broadcast whole to every
-block (they are the shared read-only side).
+The lookup is a plain XLA program, not a Pallas kernel: each probe step
+is a random gather into an index that lives in HBM and is far larger
+than VMEM at real namespace sizes, and Mosaic lowers only 2-D gathers
+from a VMEM block.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from ..phash.kernel import GOLDEN, GOLDEN2
 
@@ -43,18 +42,17 @@ def _bucket_hash(par, nam):
     return h
 
 
-def _pkval_kernel(tp_ref, tn_ref, tv_ref, par_ref, nam_ref, out_ref, *,
-                  cap: int, max_probe: int):
-    tp = tp_ref[...]                       # [cap] int32 parent / sentinel
-    tn = tn_ref[...]                       # [cap] uint32 name hash
-    tv = tv_ref[...]                       # [cap] int32 child id / -3
-    par = par_ref[...]                     # [bn] int32 probe parent
-    nam = nam_ref[...]                     # [bn] uint32 probe name hash
-    slot = _bucket_hash(par, nam) & jnp.uint32(cap - 1)
+def pkval(tp: jax.Array, tn: jax.Array, tv: jax.Array, parents: jax.Array,
+          name_hashes: jax.Array, *, max_probe: int = MAX_PROBE
+          ) -> jax.Array:
+    """index (tp/tn/tv [C]) x probes (parents/name_hashes [N]) ->
+    resolved ids [N] int32 (-1 = no such row, -3 = hash-collided bucket).
+    The hint-chain kernel runs this same lookup once per depth."""
+    cap = tp.shape[0]
+    slot = _bucket_hash(parents, name_hashes) & jnp.uint32(cap - 1)
 
     # rolled probe loop (NOT a static unroll): the XLA graph stays O(1)
-    # in max_probe, keeping compile time flat — an unrolled chain of
-    # gathers made even interpret-mode compiles pathologically slow
+    # in max_probe, keeping compile time flat
     def _step(step, carry):
         out, alive = carry
         j = ((slot + step.astype(jnp.uint32)) & jnp.uint32(cap - 1)) \
@@ -62,35 +60,12 @@ def _pkval_kernel(tp_ref, tn_ref, tv_ref, par_ref, nam_ref, out_ref, *,
         ep = jnp.take(tp, j)
         en = jnp.take(tn, j)
         ev = jnp.take(tv, j)
-        hit = alive & (ep >= 0) & (ep == par) & (en == nam)
+        hit = alive & (ep >= 0) & (ep == parents) & (en == name_hashes)
         out = jnp.where(hit, ev, out)
         alive = alive & ~hit & (ep != jnp.int32(-1))
         return out, alive
 
-    out = jnp.full(par.shape, -1, jnp.int32)
-    alive = par >= 0
+    out = jnp.full(parents.shape, -1, jnp.int32)
+    alive = parents >= 0
     out, _ = jax.lax.fori_loop(0, max_probe, _step, (out, alive))
-    out_ref[...] = out
-
-
-def pkval(tp: jax.Array, tn: jax.Array, tv: jax.Array, parents: jax.Array,
-          name_hashes: jax.Array, *, block_n: int = 1024,
-          max_probe: int = MAX_PROBE, interpret: bool = True) -> jax.Array:
-    """index (tp/tn/tv [C]) x probes (parents/name_hashes [N]) ->
-    resolved ids [N] int32 (-1 = no such row, -3 = hash-collided bucket)."""
-    (N,) = parents.shape
-    (C,) = tp.shape
-    bn = min(block_n, N)
-    kernel = functools.partial(_pkval_kernel, cap=C, max_probe=max_probe)
-    return pl.pallas_call(
-        kernel,
-        grid=(N // bn,),
-        in_specs=[pl.BlockSpec((C,), lambda i: (0,)),
-                  pl.BlockSpec((C,), lambda i: (0,)),
-                  pl.BlockSpec((C,), lambda i: (0,)),
-                  pl.BlockSpec((bn,), lambda i: (i,)),
-                  pl.BlockSpec((bn,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((N,), jnp.int32),
-        interpret=interpret,
-    )(tp, tn, tv, parents, name_hashes)
+    return out

@@ -1,6 +1,4 @@
 """jit'd wrapper + padding for the grouped PK-validation kernel."""
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,24 +7,18 @@ from ..phash.ops import _pad_pow2
 from .kernel import MAX_PROBE
 from .kernel import pkval as _pkval
 
-
-@functools.partial(jax.jit, static_argnames=("max_probe", "interpret"))
-def pkval(tp, tn, tv, parents, name_hashes, max_probe: int = MAX_PROBE,
-          interpret: bool = True):
-    return _pkval(tp, tn, tv, parents, name_hashes, max_probe=max_probe,
-                  interpret=interpret)
+pkval = jax.jit(_pkval, static_argnames=("max_probe",))
 
 
 def pkval_lookup(tp, tn, tv, parents, name_hashes, *,
-                 max_probe: int = MAX_PROBE,
-                 interpret: bool = True) -> np.ndarray:
+                 max_probe: int = MAX_PROBE) -> np.ndarray:
     """Resolve a whole batch of (parent_id, name_hash) composite-PK probes
     against the columnar store's hash index in ONE kernel launch.
 
     ``tp``/``tn``/``tv`` are the index's parent/name-hash/value arrays
     (capacity a power of two; see ``repro.core.columnar.HashIndex``).
     Probes are padded to a power-of-two length with parent ``-1`` (always a
-    miss) so the 1-D grid tiles evenly and jit recompiles stay O(log N).
+    miss) so jit recompiles stay O(log N).
     Returns ids [N] int32: resolved inode id, ``-1`` = no such row,
     ``-3`` = collided bucket (caller must fall back, not trust)."""
     par = np.asarray(parents, dtype=np.int64)
@@ -43,5 +35,5 @@ def pkval_lookup(tp, tn, tv, parents, name_hashes, *,
                 jnp.asarray(np.asarray(tn, np.uint32)),
                 jnp.asarray(np.asarray(tv, np.int32)),
                 jnp.asarray(pbuf), jnp.asarray(nbuf),
-                max_probe=max_probe, interpret=interpret)
+                max_probe=max_probe)
     return np.asarray(out)[:n]

@@ -1,4 +1,4 @@
-"""Subtree wave expansion + segment aggregation (HopsFS §6 phase 2) — Pallas.
+"""Subtree wave expansion + segment aggregation (HopsFS §6 phase 2) — XLA.
 
 The incremental subtree protocol (``repro.core.subtree``) walks a
 directory tree as BFS *waves*: a wave is the set of directory inode ids
@@ -11,99 +11,39 @@ ONE fused launch instead of a partition-pruned scan per directory:
   ``parent_id`` against the sorted wave gives ``seg``: the wave member the
   slot is a child of (``-1`` = not a child of this wave, including cleared
   slots whose parent is the ``-1`` sentinel);
-* **aggregation** — a masked scatter-add folds per-child ``1`` /
-  ``is_dir`` / ``size`` into per-wave-member ``counts`` / ``dirs`` /
-  ``sizes`` (the segment sums behind ``du`` and ``content_summary``).
+* **aggregation** — segment sums fold per-child ``1`` / ``is_dir`` /
+  ``size`` into per-wave-member ``counts`` / ``dirs`` / ``sizes`` (the
+  sums behind ``du`` and ``content_summary``).
 
 The wave is padded with ``INT32_MAX`` (never a real inode id, keeps the
 array sorted); slot-side padding uses parent ``-1`` which can never match
 a wave member (wave ids are ``>= 0``).  Everything is int32 — the suite
-runs with x64 disabled — so sizes are aggregated as int32 partial sums and
-widened host-side.
+runs with x64 disabled — so sizes are aggregated as int32 partial sums
+(wrapping, like the numpy oracle) and widened host-side.
 
-Grid: 1-D over slot blocks; the wave arrays are broadcast whole to every
-block, and the three per-wave outputs use a revisited (accumulator) block
-so each grid step adds its block's contribution.
+This is a plain XLA program: the search gathers from the wave at
+data-dependent positions and the sums scatter into it, neither of which
+Mosaic lowers from a VMEM block.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-
-def _treeagg_kernel(wave_ref, par_ref, isdir_ref, size_ref,
-                    seg_ref, cnt_ref, dir_ref, sz_ref, *,
-                    wcap: int, steps: int):
-    wave = wave_ref[...]                   # [wcap] int32 sorted wave ids
-    par = par_ref[...]                     # [bn] int32 slot parent / -1
-    isd = isdir_ref[...]                   # [bn] int32 slot is_dir (0/1)
-    siz = size_ref[...]                    # [bn] int32 slot size
-
-    # rolled lower-bound binary search (NOT a static unroll: the XLA
-    # graph stays O(1) in log(wcap), keeping interpret-mode compiles flat
-    # — same lesson as the pkval probe loop)
-    lo = jnp.zeros(par.shape, jnp.int32)
-    hi = jnp.full(par.shape, wcap, jnp.int32)
-
-    def _step(_, carry):
-        lo, hi = carry
-        cont = lo < hi
-        mid = (lo + hi) // 2
-        v = jnp.take(wave, jnp.minimum(mid, wcap - 1))
-        go_right = cont & (v < par)
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(cont & ~go_right, mid, hi)
-        return lo, hi
-
-    lo, _ = jax.lax.fori_loop(0, steps, _step, (lo, hi))
-    found = ((par >= 0) & (lo < wcap)
-             & (jnp.take(wave, jnp.minimum(lo, wcap - 1)) == par))
-    seg_ref[...] = jnp.where(found, lo, -1)
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init():                           # zero the revisited accumulators
-        cnt_ref[...] = jnp.zeros((wcap,), jnp.int32)
-        dir_ref[...] = jnp.zeros((wcap,), jnp.int32)
-        sz_ref[...] = jnp.zeros((wcap,), jnp.int32)
-
-    # masked scatter-add: misses collapse onto index 0 with value 0
-    idx = jnp.where(found, lo, 0)
-    zeros = jnp.zeros((wcap,), jnp.int32)
-    cnt_ref[...] = cnt_ref[...] + zeros.at[idx].add(
-        jnp.where(found, 1, 0).astype(jnp.int32))
-    dir_ref[...] = dir_ref[...] + zeros.at[idx].add(
-        jnp.where(found, isd, 0).astype(jnp.int32))
-    sz_ref[...] = sz_ref[...] + zeros.at[idx].add(
-        jnp.where(found, siz, 0).astype(jnp.int32))
 
 
 def treeagg(wave: jax.Array, par: jax.Array, isdir: jax.Array,
-            size: jax.Array, *, block_n: int = 8192,
-            interpret: bool = True):
+            size: jax.Array):
     """wave [W] (sorted, INT32_MAX-padded) x slots (par/isdir/size [C]) ->
     (seg [C], counts [W], dirs [W], sizes [W]) int32."""
-    (C,) = par.shape
     (W,) = wave.shape
-    bn = min(block_n, C)
-    kernel = functools.partial(_treeagg_kernel, wcap=W,
-                               steps=max(1, W.bit_length()))
-    return pl.pallas_call(
-        kernel,
-        grid=(C // bn,),
-        in_specs=[pl.BlockSpec((W,), lambda i: (0,)),
-                  pl.BlockSpec((bn,), lambda i: (i,)),
-                  pl.BlockSpec((bn,), lambda i: (i,)),
-                  pl.BlockSpec((bn,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((bn,), lambda i: (i,)),
-                   pl.BlockSpec((W,), lambda i: (0,)),
-                   pl.BlockSpec((W,), lambda i: (0,)),
-                   pl.BlockSpec((W,), lambda i: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((C,), jnp.int32),
-                   jax.ShapeDtypeStruct((W,), jnp.int32),
-                   jax.ShapeDtypeStruct((W,), jnp.int32),
-                   jax.ShapeDtypeStruct((W,), jnp.int32)],
-        interpret=interpret,
-    )(wave, par, isdir, size)
+    lo = jnp.searchsorted(wave, par, side="left").astype(jnp.int32)
+    found = ((par >= 0) & (lo < W)
+             & (jnp.take(wave, jnp.minimum(lo, W - 1)) == par))
+    seg = jnp.where(found, lo, jnp.int32(-1))
+    # misses get segment W, which segment_sum drops
+    ids = jnp.where(found, lo, jnp.int32(W))
+
+    def _sum(v):
+        return jax.ops.segment_sum(v.astype(jnp.int32), ids, num_segments=W)
+
+    return (seg, _sum(jnp.ones_like(par)), _sum(isdir), _sum(size))

@@ -1,6 +1,4 @@
 """jit'd wrapper + padding for the subtree wave-expansion kernel."""
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,14 +10,10 @@ from .kernel import treeagg as _treeagg
 #: sorted wave sorted, and slot parents can never equal it
 WAVE_PAD = np.int32(np.iinfo(np.int32).max)
 
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def treeagg(wave, par, isdir, size, interpret: bool = True):
-    return _treeagg(wave, par, isdir, size, interpret=interpret)
+treeagg = jax.jit(_treeagg)
 
 
-def treeagg_expand(wave, par, isdir, size, *,
-                   interpret: bool = True):
+def treeagg_expand(wave, par, isdir, size):
     """Resolve one BFS wave against the whole inode column set in ONE
     kernel launch.
 
@@ -27,9 +21,9 @@ def treeagg_expand(wave, par, isdir, size, *,
     ``par``/``isdir``/``size`` are the columnar table's hot columns
     (cleared slots carry parent ``-1`` and never match).  Both sides are
     padded to a power of two — wave with :data:`WAVE_PAD`, slots with
-    parent ``-1`` — so the 1-D grid tiles evenly and jit recompiles stay
-    O(log N).  Returns numpy ``(seg [C], counts [W], dirs [W],
-    sizes [W])`` int32, sliced back to the unpadded lengths."""
+    parent ``-1`` — so jit recompiles stay O(log N).  Returns numpy
+    ``(seg [C], counts [W], dirs [W], sizes [W])`` int32, sliced back to
+    the unpadded lengths."""
     wave = np.asarray(wave, dtype=np.int64)
     par = np.asarray(par, dtype=np.int64)
     w = wave.shape[0]
@@ -48,7 +42,6 @@ def treeagg_expand(wave, par, isdir, size, *,
     sbuf = np.zeros(pc, np.int32)
     sbuf[:c] = np.asarray(size, dtype=np.int64).astype(np.int32)
     seg, cnt, dirs, szs = treeagg(jnp.asarray(wbuf), jnp.asarray(pbuf),
-                                  jnp.asarray(dbuf), jnp.asarray(sbuf),
-                                  interpret=interpret)
+                                  jnp.asarray(dbuf), jnp.asarray(sbuf))
     return (np.asarray(seg)[:c], np.asarray(cnt)[:w],
             np.asarray(dirs)[:w], np.asarray(szs)[:w])

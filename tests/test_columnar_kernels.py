@@ -1,8 +1,11 @@
-"""Pallas (interpret=True) vs numpy-oracle regression for the columnar
-engine's two fused kernels — grouped PK validation (``pkval``) and
-vectorized hint-chain resolution (``hintchain``) — mirroring the phash
-suite's pattern, plus ``_KernelProbe`` fallback-and-recovery coverage for
-the per-family availability gates."""
+"""Kernel vs numpy-oracle regression for the columnar engine's two fused
+lookups — grouped PK validation (``pkval``) and vectorized hint-chain
+resolution (``hintchain``), both jitted XLA programs that run on whatever
+backend JAX has (the CPU under ``JAX_PLATFORMS=cpu``) — mirroring the
+phash suite's pattern, plus ``_KernelProbe`` fallback-and-recovery
+coverage for the per-family availability gates, whose host fallback is
+taken only where ``repro.kernels.mode`` says kernels are interpreted."""
+import jax
 import numpy as np
 import pytest
 
@@ -182,6 +185,30 @@ def test_hintchain_empty_window():
 
 
 # ---------------------------------------------------------------------------
+# treeagg
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n_slots,n_wave", [(8, 1), (300, 7), (5000, 130)])
+def test_treeagg_kernel_matches_ref(n_slots, n_wave):
+    """Wave expansion + segment sums agree bit for bit with the oracle:
+    cleared slots (parent -1), non-members, and int32 size sums that
+    wrap past 2^31 exactly like the oracle's."""
+    from repro.kernels.treeagg.ops import treeagg_expand
+    from repro.kernels.treeagg.ref import treeagg_ref
+    rng = np.random.default_rng(n_slots)
+    wave = np.unique(rng.choice(np.arange(2, 400), n_wave, replace=False))
+    par = rng.integers(-1, 400, n_slots)
+    isdir = rng.integers(0, 2, n_slots)
+    size = rng.integers(0, 2**31 - 1, n_slots)
+    got = treeagg_expand(wave, par, isdir, size)
+    want = treeagg_ref(wave, par, isdir, size)
+    for g, w in zip(got, want):
+        assert g.dtype == np.int32 and (g == w).all()
+    seg = got[0]
+    assert ((seg >= 0) == np.isin(par, wave)).all()
+
+
+# ---------------------------------------------------------------------------
 # _KernelProbe fallback & recovery (per-family gates)
 # ---------------------------------------------------------------------------
 
@@ -215,6 +242,52 @@ def test_kernel_probe_fallback_and_bounded_recovery():
     out, used = _with_phash_kernel(good_kernel, fallback, n_keys=100,
                                    min_batch=2, probe=probe)
     assert out == "kernel" and used and probe.failures == 0
+
+
+def test_kernel_probe_counts_launches_and_demotions():
+    probe = _KernelProbe()
+    _with_phash_kernel(lambda: "k", lambda: "f", n_keys=1, min_batch=2,
+                       probe=probe)
+    assert (probe.launches, probe.demotions) == (0, 0)   # below the gate
+    _with_phash_kernel(lambda: "k", lambda: "f", n_keys=4, min_batch=2,
+                       probe=probe)
+    assert (probe.launches, probe.demotions) == (1, 0)
+
+    def bad():
+        raise RuntimeError("kernel bug")
+
+    out, used = _with_phash_kernel(bad, lambda: "f", n_keys=4, min_batch=2,
+                                   probe=probe)
+    assert (out, used) == ("f", False)
+    assert (probe.launches, probe.demotions) == (1, 1)
+
+
+def test_compiled_kernel_failure_propagates(monkeypatch):
+    """Where kernels run compiled (the TPU), a failing kernel raises out
+    of the gate instead of answering from the host oracle — and is still
+    counted as a demotion."""
+    from repro.kernels import mode
+    monkeypatch.setattr(mode, "interpret", lambda: False)
+    probe = _KernelProbe()
+
+    def bad():
+        raise RuntimeError("kernel bug")
+
+    with pytest.raises(RuntimeError, match="kernel bug"):
+        _with_phash_kernel(bad, lambda: "f", n_keys=4, min_batch=2,
+                           probe=probe)
+    assert (probe.launches, probe.demotions) == (0, 1)
+
+
+def test_kernel_mode_follows_backend(monkeypatch):
+    from repro.kernels import mode
+    assert mode.interpret() is (jax.default_backend() == "cpu")
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert mode.interpret() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        mode.interpret()
 
 
 def test_kernel_probe_families_are_independent():

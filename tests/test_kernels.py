@@ -1,5 +1,8 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp
-oracle, plus oracle-vs-explicit-recurrence cross-checks."""
+"""Per-kernel shape/dtype sweeps: Pallas vs pure-jnp oracle, plus
+oracle-vs-explicit-recurrence cross-checks. The metadata kernels (phash)
+run in the mode ``repro.kernels.mode`` derives from the backend — the
+Pallas interpreter under ``JAX_PLATFORMS=cpu``; the model-stack kernels
+default to the interpreter."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -265,9 +268,11 @@ def test_phash_chain_kernel_matches_ref_and_store():
 
 
 def test_phash_kernel_matches_ref():
+    from repro.kernels import mode
     from repro.kernels.phash.kernel import phash
     from repro.kernels.phash.ref import phash_ref
     keys = jnp.asarray((np.arange(8192, dtype=np.uint64) * 2654435761
                         % (2**31)).astype(np.int32))
-    out = phash(keys, n_partitions=128, block_n=512)
+    out = phash(keys, n_partitions=128, block_n=512,
+                interpret=mode.interpret())
     assert (np.asarray(out) == phash_ref(keys, 128)).all()
