@@ -1,4 +1,4 @@
-"""Benchmark harness: one function per paper table/figure (+ roofline).
+"""Benchmark harness: one function per paper table/figure.
 Prints ``name,us_per_call,derived`` CSV.
 
   PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig8]
@@ -14,7 +14,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from benchmarks import paper_benches as pb            # noqa: E402
-from benchmarks.roofline import bench_roofline        # noqa: E402
 from benchmarks.trace_replay import bench_trace_replay  # noqa: E402
 from repro.kernels.mode import use_compile_cache      # noqa: E402
 
@@ -32,7 +31,6 @@ BENCHES = [
     ("table3", pb.bench_table3_costmodel),
     ("trace_replay", bench_trace_replay),
     ("ckpt", pb.bench_ckpt_metadata),
-    ("roofline", bench_roofline),
 ]
 
 

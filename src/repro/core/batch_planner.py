@@ -51,6 +51,7 @@ writes group concurrently.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 from collections import deque
 from dataclasses import dataclass, field
@@ -61,6 +62,7 @@ from .hint_cache import InodeHintCache, absorb_response
 from .namenode import (NamenodeCluster, OpOutcome, PipelineStats, PlanHint,
                        RequestPipeline, _KernelProbe, _with_phash_kernel)
 from .ops_registry import REGISTRY, WorkloadOp
+from .spans import span
 from .store import StoreError
 from .tables import split_path
 from .workload import ColumnarTrace
@@ -71,7 +73,9 @@ __all__ = ["BatchPlanner", "HintResolver", "MultiCacheResolver",
 
 # the planner's fused chain hash has its own gate and counters, apart
 # from the namenodes' single-key phash batches
-_phash_chain_probe = _KernelProbe()
+_phash_chain_probe = _KernelProbe("phash_chain")
+#: sequence numbers of planner windows, unique in the process
+_window_seq = itertools.count()
 
 
 class MultiCacheResolver:
@@ -214,12 +218,15 @@ class PlannedBatch:
     is always an atomic unit of per-file block-write ordering; ``mutates``
     marks batches carrying any mutation — concurrent workers never steal
     those, so a partition's writes always land on its home namenode
-    (warm hint cache, stable grouped-write engagement)."""
+    (warm hint cache, stable grouped-write engagement). ``window`` is
+    the sequence number of the planner window that dealt it (None for
+    failover re-deals)."""
     indices: List[int]
     hints: List[Optional[PlanHint]]
     nn_slot: int
     ordered: bool = False
     mutates: bool = False
+    window: Optional[int] = None
 
 
 @dataclass
@@ -496,8 +503,15 @@ class BatchPlanner:
         """Plan ONE window of the trace (global indices [lo, hi)). The
         closed-loop pipeline calls this per window — executing and
         absorbing response hints between calls — so each window resolves
-        against the freshest client cache state."""
-        n_partitions = self.cluster.store.n_partitions
+        against the freshest client cache state. The window's sequence
+        number (unique in the process) is recorded on its span and on its
+        batches, whichever thread runs them."""
+        seq = next(_window_seq)
+        with span("planner.window", window=seq):
+            return self._plan_window(wops, lo, hi, seq)
+
+    def _plan_window(self, wops: Sequence[WorkloadOp], lo: int, hi: int,
+                     seq: int) -> List[PlannedBatch]:
         # membership is LIVE under the elastic pool: re-derive the slot
         # count per window so dealt batches spread over the namenodes
         # alive NOW (on a static fleet this is the frozen constructor
@@ -511,7 +525,6 @@ class BatchPlanner:
             resolver: Any = self._resolver
         else:
             resolver = fallback
-        batches: List[PlannedBatch] = []
         self.report.ops += hi - lo
         window = list(range(lo, hi))
         # deadline-aware dealing: deal only ops that can still make
@@ -529,29 +542,43 @@ class BatchPlanner:
             self.report.windows += 1
             self.report.window_sizes.append(hi - lo)
             self._refresh_client_telemetry()
-            return batches
+            return []
         # fused hint-chain resolution: one hintchain launch walks every
         # op's cached parent chain (bit-equivalent to the Python loop,
         # which small windows and non-HintResolver resolvers fall back to)
-        ct, used_hintchain = lower_trace_fused(
-            [wops[i] for i in window], resolver)
+        with span("planner.lower"):
+            ct, used_hintchain = lower_trace_fused(
+                [wops[i] for i in window], resolver)
         if used_hintchain:
             self.report.hintchain_launches += 1
         # grouped-batch PK validation: one pkval launch checks every
         # client-resolved chain against the columnar store's hash index;
         # stale chains are demoted BEFORE the conflict/pinning pass so
         # they ride the exact sequential path (dict backend: no-op)
-        validated = validate_window_pks(self.cluster.store, ct)
-        if validated is not None:
-            demoted, n_probes, used_pkval = validated
-            self.report.pkval_probes += n_probes
-            if used_pkval:
-                self.report.pkval_launches += 1
-            for k in demoted:
-                self.report.pkval_demotions += 1
-                ct.resolved[k] = False
-                ct.pks[k] = None
-                ct.target_ids[k] = None
+        with span("planner.validate"):
+            validated = validate_window_pks(self.cluster.store, ct)
+            if validated is not None:
+                demoted, n_probes, used_pkval = validated
+                self.report.pkval_probes += n_probes
+                if used_pkval:
+                    self.report.pkval_launches += 1
+                for k in demoted:
+                    self.report.pkval_demotions += 1
+                    ct.resolved[k] = False
+                    ct.pks[k] = None
+                    ct.target_ids[k] = None
+        with span("planner.deal"):
+            return self._deal(wops, lo, hi, window, ct, alive, seq)
+
+    def _deal(self, wops: Sequence[WorkloadOp], lo: int, hi: int,
+              window: List[int], ct: ColumnarTrace, alive: Sequence[Any],
+              seq: int) -> List[PlannedBatch]:
+        """Pin the window's conflicting ops and deal the rest into
+        partition-aligned, type-sorted batches (steps 2-4 of the module
+        doc); ``window`` holds the indices left after deadline shedding,
+        ``ct`` their lowered, validated chains."""
+        n_partitions = self.cluster.store.n_partitions
+        batches: List[PlannedBatch] = []
         # _sigs: the kernel's path-equality probe, no consumer here yet
         comp_parts, hint_parts, _sigs, used_kernel = _chain_partitions(
             ct, n_partitions)
@@ -640,7 +667,7 @@ class BatchPlanner:
                 for i in chunk)
             batches.append(PlannedBatch(
                 indices=chunk, hints=[hints[i] for i in chunk],
-                nn_slot=slot, mutates=mutates))
+                nn_slot=slot, mutates=mutates, window=seq))
         # pinned mutations LAST, strictly in submission order: free
         # reads of a window never spuriously fail against a
         # destructive op the trace issued later (a read the trace
@@ -653,7 +680,7 @@ class BatchPlanner:
             chunk = pin_order[c:c + self.batch_size]
             batches.append(PlannedBatch(
                 indices=chunk, hints=[hints[i] for i in chunk],
-                nn_slot=pin_slot, ordered=True))
+                nn_slot=pin_slot, ordered=True, window=seq))
         self.report.windows += 1
         self.report.window_sizes.append(hi - lo)
         self.report.batches += len(batches)
@@ -774,13 +801,14 @@ class PlannedRequestPipeline(RequestPipeline):
         rule: invalidate-on-destructive per op, then warm), and return
         the window's measured DB round trips for the controller."""
         round_trips = 0
-        for i in range(lo, hi):
-            oc = outcomes[i]
-            if oc is None or not oc.ok:
-                continue
-            round_trips += oc.result.cost.round_trips
-            absorb_response(self.client_cache, wops[i],
-                            REGISTRY.get(wops[i].op), oc.result.hints)
+        with span("planner.absorb"):
+            for i in range(lo, hi):
+                oc = outcomes[i]
+                if oc is None or not oc.ok:
+                    continue
+                round_trips += oc.result.cost.round_trips
+                absorb_response(self.client_cache, wops[i],
+                                REGISTRY.get(wops[i].op), oc.result.hints)
         return round_trips
 
     def run(self, wops: Sequence[WorkloadOp]) -> PipelineStats:
@@ -809,14 +837,20 @@ class PlannedRequestPipeline(RequestPipeline):
             """Execute one planned batch; False if the namenode died (its
             unfinished ops go to the residual queue)."""
             try:
-                res = nn.execute_batch([wops[i] for i in batch.indices],
-                                       hints=batch.hints)
+                # the window's number ties a batch run on a worker
+                # thread to its window
+                with span("namenode.batch", window=batch.window):
+                    res = nn.execute_batch([wops[i] for i in batch.indices],
+                                           hints=batch.hints)
             except StoreError:
                 if self.breakers is not None:
                     self.breakers.record(nn.nn_id, ok=False)
                 with rlock:
                     residual.extend(batch.indices)
                 return False
+            done = time.perf_counter()
+            for oc in res:
+                oc.done_s = done
             died = []
             for i, oc in zip(batch.indices, res):
                 if not oc.ok and oc.error == "StoreError" and not nn.alive:

@@ -44,6 +44,7 @@ from .namenode import _KernelProbe, _with_phash_kernel
 from .store import MetadataStore
 from .tables import ROOT_ID, TableSchema, pk_of
 from .ops_registry import REGISTRY
+from .spans import span
 from .workload import ColumnarTrace, WorkloadOp, lower_trace, name_hash32
 
 # sentinels — MUST match repro.kernels.pkval.kernel (asserted by the
@@ -70,9 +71,9 @@ TREEAGG_MIN_BATCH = 128
 
 # per-family availability gates: a pkval failure must not latch the
 # hintchain (or phash) fallback, and vice versa
-_pkval_probe = _KernelProbe()
-_hintchain_probe = _KernelProbe()
-_treeagg_probe = _KernelProbe()
+_pkval_probe = _KernelProbe("pkval")
+_hintchain_probe = _KernelProbe("hintchain")
+_treeagg_probe = _KernelProbe("treeagg")
 
 _MISSING = object()          # column sentinel: row has no such key
 
@@ -556,23 +557,24 @@ def _snapshot_resolver(cache: Any, fallback: Any
     None when a view cannot be represented (unknown resolver shape)."""
     if not hasattr(cache, "export_entries"):
         return None
-    cidx = HashIndex.from_entries(cache.export_entries())
-    if fallback is None:
-        fidx = HashIndex()
-    elif hasattr(fallback, "caches"):
-        # MultiCacheResolver precedence: first cache that knows a key wins
-        merged: Dict[Tuple[int, str], int] = {}
-        for c in fallback.caches:
-            if not hasattr(c, "export_entries"):
-                return None
-            for par, name, iid in c.export_entries():
-                merged.setdefault((par, name), iid)
-        fidx = HashIndex.from_entries(
-            (par, name, iid) for (par, name), iid in merged.items())
-    elif hasattr(fallback, "export_entries"):
-        fidx = HashIndex.from_entries(fallback.export_entries())
-    else:
-        return None
+    with span("planner.snapshot"):
+        cidx = HashIndex.from_entries(cache.export_entries())
+        if fallback is None:
+            fidx = HashIndex()
+        elif hasattr(fallback, "caches"):
+            # MultiCacheResolver precedence: first cache that knows a key wins
+            merged: Dict[Tuple[int, str], int] = {}
+            for c in fallback.caches:
+                if not hasattr(c, "export_entries"):
+                    return None
+                for par, name, iid in c.export_entries():
+                    merged.setdefault((par, name), iid)
+            fidx = HashIndex.from_entries(
+                (par, name, iid) for (par, name), iid in merged.items())
+        elif hasattr(fallback, "export_entries"):
+            fidx = HashIndex.from_entries(fallback.export_entries())
+        else:
+            return None
     return cidx, fidx
 
 

@@ -28,6 +28,7 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
+from ..kernels.handover import handed_bytes
 from .fs import (FSError, HopsFSOps, OpResult, SubtreeLockedError,
                  split_path)
 from .leader import LeaderElection
@@ -36,6 +37,7 @@ from .middleware import (CallContext, compose, failover, subtree_retry,
 from .ops_registry import GroupWriteCtx, REGISTRY, WorkloadOp
 from .store import (EXCLUSIVE, MetadataStore, OpCost, READ_COMMITTED,
                     SHARED, StoreError, _hash_key)
+from .spans import span
 from .subtree import SubtreeOps
 from .tables import ROOT_ID
 from .transactions import Transaction
@@ -70,14 +72,18 @@ class _KernelProbe:
 
     ``launches`` counts calls the kernel served; ``demotions`` counts calls
     above the size gate that it did not (a failure, or the latched
-    fallback), so a run can prove its device path actually ran."""
+    fallback), so a run can prove its device path actually ran;
+    ``h2d_bytes`` sums the host arrays the served calls handed to the
+    device. Each launch is one ``kernel.<family>`` span."""
 
-    def __init__(self, reprobe_every: int = 64):
+    def __init__(self, family: str, reprobe_every: int = 64):
+        self.family = family
         self.reprobe_every = reprobe_every
         self.failures = 0                  # consecutive probe failures
         self._calls_since_failure = 0
         self.launches = 0
         self.demotions = 0
+        self.h2d_bytes = 0
 
     def usable(self) -> bool:
         if self.failures == 0:
@@ -97,7 +103,7 @@ class _KernelProbe:
         self._calls_since_failure = 0
 
 
-_phash_probe = _KernelProbe()
+_phash_probe = _KernelProbe("phash")
 
 
 def _with_phash_kernel(kernel_fn: Any, fallback_fn: Any, *, n_keys: int,
@@ -118,8 +124,10 @@ def _with_phash_kernel(kernel_fn: Any, fallback_fn: Any, *, n_keys: int,
     gate = probe if probe is not None else _phash_probe
     if n_keys >= max(2, min_batch):
         if gate.usable():
+            handed0 = handed_bytes()
             try:
-                out = kernel_fn()
+                with span(f"kernel.{gate.family}"):
+                    out = kernel_fn()
             except Exception:
                 gate.failed()
                 from ..kernels import mode
@@ -129,6 +137,7 @@ def _with_phash_kernel(kernel_fn: Any, fallback_fn: Any, *, n_keys: int,
             else:
                 gate.succeeded()
                 gate.launches += 1
+                gate.h2d_bytes += handed_bytes() - handed0
                 return out, True
         gate.demotions += 1
     return fallback_fn(), False
@@ -167,10 +176,13 @@ class PlanHint:
 @dataclass
 class OpOutcome:
     """Per-op outcome from the batched pipeline: either a result or the
-    name of the FS error that sequential execution would have raised."""
+    name of the FS error that sequential execution would have raised.
+    ``done_s`` is the ``time.perf_counter()`` at which the batch that
+    served the op returned (None where no batch did)."""
     result: Optional[OpResult]
     error: Optional[str] = None
     batched: bool = False
+    done_s: Optional[float] = None
 
     @property
     def ok(self) -> bool:
@@ -380,11 +392,12 @@ class Namenode:
                 [subtree_retry(retries=retries, backoff=backoff),
                  txn_retry()],
                 lambda ctx: self.invoke(ctx.wop))
-        try:
-            return OpOutcome(handler(CallContext(op=wop.op, wop=wop,
-                                                 namenode=self)))
-        except StoreError as e:      # includes surfaced SubtreeLockedError
-            return OpOutcome(None, type(e).__name__)
+        with span("namenode.single"):
+            try:
+                return OpOutcome(handler(CallContext(op=wop.op, wop=wop,
+                                                     namenode=self)))
+            except StoreError as e:  # includes surfaced SubtreeLockedError
+                return OpOutcome(None, type(e).__name__)
 
     def execute_batch(self, wops: Sequence[WorkloadOp],
                       hints: Optional[Sequence[Optional[PlanHint]]] = None
@@ -443,11 +456,13 @@ class Namenode:
                     j += 1
                 if j - i > 1:
                     if spec.batchable:
-                        self._execute_read_run(op, wops, i, j, results,
-                                               hints)
+                        with span("namenode.read_run"):
+                            self._execute_read_run(op, wops, i, j, results,
+                                                   hints)
                     else:
-                        self._execute_write_run(op, wops, i, j, results,
-                                                hints)
+                        with span("namenode.write_run"):
+                            self._execute_write_run(op, wops, i, j, results,
+                                                    hints)
                 else:
                     results[i] = self._safe_exec(wops[i])
             else:
@@ -461,21 +476,22 @@ class Namenode:
         # once per DISTINCT client, not per op: all stamps in one batch
         # share the same logical tick, so N touches of one hot client
         # would just be N redundant lock round trips)
-        clients: Set[str] = set()
-        for wop, oc in zip(wops, results):
-            if oc is None or not oc.ok or not oc.batched:
-                continue
-            spec = REGISTRY.get(wop.op)
-            if spec is None:
-                continue
-            paths, kw = spec.call_args(wop)
-            oc.result.hints = self._piggyback_hints(paths) \
-                + self.store.hint_piggyback()
-            if spec.has_client_arg and not spec.renews_lease \
-                    and "client" in kw:
-                clients.add(kw["client"])
-        for client in sorted(clients):
-            self.ops.touch_lease(client)
+        with span("namenode.piggyback"):
+            clients: Set[str] = set()
+            for wop, oc in zip(wops, results):
+                if oc is None or not oc.ok or not oc.batched:
+                    continue
+                spec = REGISTRY.get(wop.op)
+                if spec is None:
+                    continue
+                paths, kw = spec.call_args(wop)
+                oc.result.hints = self._piggyback_hints(paths) \
+                    + self.store.hint_piggyback()
+                if spec.has_client_arg and not spec.renews_lease \
+                        and "client" in kw:
+                    clients.add(kw["client"])
+            for client in sorted(clients):
+                self.ops.touch_lease(client)
         return results  # type: ignore[return-value]
 
     def _execute_read_run(self, op: str, wops: Sequence[WorkloadOp],
@@ -1185,10 +1201,14 @@ class RequestPipeline:
             """One batch on one namenode; False if the NN died mid-run (the
             batch is requeued for the survivors — §7.6.1 failover)."""
             try:
-                res = nn.execute_batch([wops[i] for i in idxs])
+                with span("namenode.batch"):
+                    res = nn.execute_batch([wops[i] for i in idxs])
             except StoreError:
                 requeue(idxs)
                 return False
+            done = time.perf_counter()
+            for oc in res:
+                oc.done_s = done
             retry: List[int] = []
             for i, oc in zip(idxs, res):
                 if not oc.ok and oc.error == "StoreError" and not nn.alive:
@@ -1256,40 +1276,42 @@ class RequestPipeline:
         """Conserved-accounting roll-up shared by the reactive and planned
         pipelines: per-namenode cost deltas, total cost over successful
         outcomes, and the batched read/write op split."""
-        # namenodes absent from the snapshots joined mid-run (elastic
-        # scale-out): their whole lifetime cost belongs to this run
-        per_nn_cost = {nn.nn_id: nn.agg_cost.diff(cost0.get(nn.nn_id,
-                                                            OpCost()))
-                       for nn in self.cluster.namenodes}
-        per_nn_ops = {nn.nn_id: nn.ops_served - served0.get(nn.nn_id, 0)
-                      for nn in self.cluster.namenodes}
-        total = OpCost()
-        ok = failed = 0
-        for oc in outcomes:
-            if oc.ok:
-                ok += 1
-                total.merge(oc.result.cost)  # type: ignore[union-attr]
-            else:
-                failed += 1
-        b_reads = b_writes = 0
-        for wop, oc in zip(wops, outcomes):
-            # only SERVED ops count toward the read/write batched split,
-            # matching the per-namenode batched_ops/batched_write_ops
-            # counters (a grouped op that errored is not "served by" the
-            # grouped transaction)
-            if oc is not None and oc.batched and oc.ok:
-                s = REGISTRY.get(wop.op)
-                if s is not None and s.read_only:
-                    b_reads += 1
+        with span("client.finalize"):
+            # namenodes absent from the snapshots joined mid-run (elastic
+            # scale-out): their whole lifetime cost belongs to this run
+            per_nn_cost = {nn.nn_id: nn.agg_cost.diff(cost0.get(nn.nn_id,
+                                                                OpCost()))
+                           for nn in self.cluster.namenodes}
+            per_nn_ops = {nn.nn_id: nn.ops_served - served0.get(nn.nn_id, 0)
+                          for nn in self.cluster.namenodes}
+            total = OpCost()
+            ok = failed = 0
+            for oc in outcomes:
+                if oc.ok:
+                    ok += 1
+                    total.merge(oc.result.cost)  # type: ignore[union-attr]
                 else:
-                    b_writes += 1
-        return PipelineStats(outcomes=list(outcomes),  # type: ignore
-                             per_nn_cost=per_nn_cost, per_nn_ops=per_nn_ops,
-                             total_cost=total, ok=ok, failed=failed,
-                             wall_s=wall, batch_size=self.batch_size,
-                             n_batches=n_batches,
-                             batched_read_ops=b_reads,
-                             batched_write_ops=b_writes)
+                    failed += 1
+            b_reads = b_writes = 0
+            for wop, oc in zip(wops, outcomes):
+                # only SERVED ops count toward the read/write batched split,
+                # matching the per-namenode batched_ops/batched_write_ops
+                # counters (a grouped op that errored is not "served by" the
+                # grouped transaction)
+                if oc is not None and oc.batched and oc.ok:
+                    s = REGISTRY.get(wop.op)
+                    if s is not None and s.read_only:
+                        b_reads += 1
+                    else:
+                        b_writes += 1
+            return PipelineStats(outcomes=list(outcomes),  # type: ignore
+                                 per_nn_cost=per_nn_cost,
+                                 per_nn_ops=per_nn_ops,
+                                 total_cost=total, ok=ok, failed=failed,
+                                 wall_s=wall, batch_size=self.batch_size,
+                                 n_batches=n_batches,
+                                 batched_read_ops=b_reads,
+                                 batched_write_ops=b_writes)
 
 
 def namespace_snapshot(store: MetadataStore) -> Dict[str, Tuple]:

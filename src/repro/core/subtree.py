@@ -56,6 +56,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from .fs import (FSError, FileAlreadyExists, FileNotFound, HopsFSOps,
                  OpResult, SubtreeLockedError, split_path)
+from .spans import span
 from .store import EXCLUSIVE, OpCost
 from .transactions import Transaction
 
@@ -365,7 +366,8 @@ class SubtreeOps:
         except Exception:                    # pragma: no cover - import guard
             return None
         try:
-            exp = expand_wave(self.store, dir_ids)
+            with span("namenode.subtree_wave"):
+                exp = expand_wave(self.store, dir_ids)
         except Exception:                    # pragma: no cover - advisory
             # the transactional scans stay authoritative, but a launch
             # that failed is counted, never skipped in silence

@@ -1,8 +1,8 @@
 """jit'd wrapper + padding for the hint-chain resolution kernel."""
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+from ..handover import to_device
 from ..phash.ops import _pad_pow2
 from ..pkval.kernel import MAX_PROBE
 from .kernel import hintchain as _hintchain
@@ -37,9 +37,8 @@ def hintchain_resolve(client_idx, fallback_idx, name_hashes, depths, *,
     cp, cn_, cv = (np.asarray(a) for a in client_idx)
     fp, fn_, fv = (np.asarray(a) for a in fallback_idx)
     childs, srcs = hintchain(
-        jnp.asarray(cp.astype(np.int32)), jnp.asarray(cn_.astype(np.uint32)),
-        jnp.asarray(cv.astype(np.int32)), jnp.asarray(fp.astype(np.int32)),
-        jnp.asarray(fn_.astype(np.uint32)), jnp.asarray(fv.astype(np.int32)),
-        jnp.asarray(nbuf), jnp.asarray(dbuf), root_id=root_id,
-        max_probe=max_probe)
+        *to_device(cp.astype(np.int32), cn_.astype(np.uint32),
+                   cv.astype(np.int32), fp.astype(np.int32),
+                   fn_.astype(np.uint32), fv.astype(np.int32), nbuf, dbuf),
+        root_id=root_id, max_probe=max_probe)
     return np.asarray(childs)[:n], np.asarray(srcs)[:n]

@@ -2,10 +2,10 @@
 import functools
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from .. import mode
+from ..handover import to_device
 from .kernel import phash as _phash
 from .kernel import phash_chain as _phash_chain
 
@@ -44,7 +44,7 @@ def phash_partitions(keys, n_partitions: int = 64) -> np.ndarray:
         padded *= 2
     buf = np.zeros(padded, dtype=np.uint32)
     buf[:n] = arr.astype(np.uint32)
-    out = phash(jnp.asarray(buf), n_partitions=n_partitions)
+    out = phash(*to_device(buf), n_partitions=n_partitions)
     return np.asarray(out)[:n]
 
 
@@ -93,7 +93,6 @@ def phash_chains(parent_ids, name_hashes, hint_ids, depths,
     dbuf = np.zeros((1, pn), np.int32)
     dbuf[0, :n] = dep
     comp, hint_parts, sigs = phash_chain(
-        jnp.asarray(pbuf), jnp.asarray(nbuf), jnp.asarray(hbuf),
-        jnp.asarray(dbuf), n_partitions=n_partitions)
+        *to_device(pbuf, nbuf, hbuf, dbuf), n_partitions=n_partitions)
     return (np.asarray(comp)[:, :n].T, np.asarray(hint_parts)[0, :n],
             np.asarray(sigs)[0, :n])

@@ -1,8 +1,8 @@
 """jit'd wrapper + padding for the grouped PK-validation kernel."""
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+from ..handover import to_device
 from ..phash.ops import _pad_pow2
 from .kernel import MAX_PROBE
 from .kernel import pkval as _pkval
@@ -31,9 +31,8 @@ def pkval_lookup(tp, tn, tv, parents, name_hashes, *,
     pbuf[:n] = par.astype(np.int32)
     nbuf = np.zeros(pn, np.uint32)
     nbuf[:n] = nam.astype(np.uint32)
-    out = pkval(jnp.asarray(np.asarray(tp, np.int32)),
-                jnp.asarray(np.asarray(tn, np.uint32)),
-                jnp.asarray(np.asarray(tv, np.int32)),
-                jnp.asarray(pbuf), jnp.asarray(nbuf),
+    out = pkval(*to_device(np.asarray(tp, np.int32),
+                           np.asarray(tn, np.uint32),
+                           np.asarray(tv, np.int32), pbuf, nbuf),
                 max_probe=max_probe)
     return np.asarray(out)[:n]

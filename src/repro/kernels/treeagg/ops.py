@@ -1,8 +1,8 @@
 """jit'd wrapper + padding for the subtree wave-expansion kernel."""
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+from ..handover import to_device
 from ..phash.ops import _pad_pow2
 from .kernel import treeagg as _treeagg
 
@@ -41,7 +41,6 @@ def treeagg_expand(wave, par, isdir, size):
     dbuf[:c] = np.asarray(isdir, dtype=np.int64).astype(np.int32)
     sbuf = np.zeros(pc, np.int32)
     sbuf[:c] = np.asarray(size, dtype=np.int64).astype(np.int32)
-    seg, cnt, dirs, szs = treeagg(jnp.asarray(wbuf), jnp.asarray(pbuf),
-                                  jnp.asarray(dbuf), jnp.asarray(sbuf))
+    seg, cnt, dirs, szs = treeagg(*to_device(wbuf, pbuf, dbuf, sbuf))
     return (np.asarray(seg)[:c], np.asarray(cnt)[:w],
             np.asarray(dirs)[:w], np.asarray(szs)[:w])

@@ -375,7 +375,7 @@ def test_phash_fallback_recovers_after_transient_failure(monkeypatch):
     process lifetime)."""
     import repro.kernels.phash.ops as phash_ops
     from repro.core import namenode as nn_mod
-    probe = nn_mod._KernelProbe(reprobe_every=3)
+    probe = nn_mod._KernelProbe("phash", reprobe_every=3)
     monkeypatch.setattr(nn_mod, "_phash_probe", probe)
     calls = {"kernel": 0, "fail_next": 1}
     real = phash_ops.phash_partitions

@@ -213,7 +213,7 @@ def test_treeagg_kernel_matches_ref(n_slots, n_wave):
 # ---------------------------------------------------------------------------
 
 def test_kernel_probe_fallback_and_bounded_recovery():
-    probe = _KernelProbe(reprobe_every=4)
+    probe = _KernelProbe("pkval", reprobe_every=4)
     calls = {"kern": 0, "fall": 0}
 
     def bad_kernel():
@@ -245,7 +245,7 @@ def test_kernel_probe_fallback_and_bounded_recovery():
 
 
 def test_kernel_probe_counts_launches_and_demotions():
-    probe = _KernelProbe()
+    probe = _KernelProbe("pkval")
     _with_phash_kernel(lambda: "k", lambda: "f", n_keys=1, min_batch=2,
                        probe=probe)
     assert (probe.launches, probe.demotions) == (0, 0)   # below the gate
@@ -268,7 +268,7 @@ def test_compiled_kernel_failure_propagates(monkeypatch):
     counted as a demotion."""
     from repro.kernels import mode
     monkeypatch.setattr(mode, "interpret", lambda: False)
-    probe = _KernelProbe()
+    probe = _KernelProbe("pkval")
 
     def bad():
         raise RuntimeError("kernel bug")
