@@ -106,7 +106,10 @@ class HintResolver:
     (the merged namenode caches) only on a miss. Probe-level telemetry:
     ``hits`` (client cache), ``fallback_hits`` (namenode caches vouched),
     ``misses`` (nobody knew — the op stays unresolved or resolves
-    server-side)."""
+    server-side). ``snapshot_rebuilds`` and ``snapshot_delta_keys``
+    count how the fused lowering's persistent hash-index snapshots of the
+    two views were brought up to date: full builds of either view, and
+    dirty keys applied from the caches' change journals."""
 
     def __init__(self, cache: InodeHintCache, fallback: Any = None):
         self.cache = cache
@@ -114,6 +117,8 @@ class HintResolver:
         self.hits = 0
         self.fallback_hits = 0
         self.misses = 0
+        self.snapshot_rebuilds = 0
+        self.snapshot_delta_keys = 0
 
     def peek(self, parent_id: int, name: str) -> Optional[int]:
         v = self.cache.peek(parent_id, name)
@@ -239,7 +244,8 @@ class PlanReport:
     hint telemetry: probe-level hits on the client's own response-warmed
     cache vs fallback hits on the merged namenode caches vs misses, plus
     staleness evidence (absorbed hints contradicting cached ids, and
-    client-side invalidations on destructive ops)."""
+    client-side invalidations on destructive ops). ``snapshot_*`` count
+    the upkeep of the ``hintchain`` snapshots (:class:`HintResolver`)."""
     ops: int = 0
     planned_ops: int = 0        # ops dealt with a client-side resolution
     pinned_ops: int = 0         # mutations kept in submission order
@@ -263,6 +269,8 @@ class PlanReport:
     client_misses: int = 0
     client_stale: int = 0          # absorbed hints contradicting cached ids
     client_invalidations: int = 0  # destructive-op invalidations
+    snapshot_rebuilds: int = 0     # full builds of either hint snapshot
+    snapshot_delta_keys: int = 0   # dirty keys applied to the snapshots
     hint_routed_batches: int = 0   # batches dealt to a warm namenode
                                    # instead of the partition-hash slot
     deadline_shed: int = 0         # ops never dealt: deadline already past
@@ -695,6 +703,9 @@ class BatchPlanner:
             self.report.client_hits = self._resolver.hits
             self.report.client_fallback_hits = self._resolver.fallback_hits
             self.report.client_misses = self._resolver.misses
+            self.report.snapshot_rebuilds = self._resolver.snapshot_rebuilds
+            self.report.snapshot_delta_keys = \
+                self._resolver.snapshot_delta_keys
         if self.client_cache is not None:
             self.report.client_stale = \
                 self.client_cache.stale_overwrites - self._stale0

@@ -35,6 +35,8 @@ Sentinel encoding shared by the host index and both kernels::
 """
 from __future__ import annotations
 
+import threading
+import weakref
 from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
                     Set, Tuple)
 
@@ -76,6 +78,16 @@ _hintchain_probe = _KernelProbe("hintchain")
 _treeagg_probe = _KernelProbe("treeagg")
 
 _MISSING = object()          # column sentinel: row has no such key
+
+#: the least slots per live entry the planner's persistent hint snapshots
+#: keep: the density a fresh ``from_entries`` build of hint-cache contents
+#: lands at (8-10 slots per entry: its linear-probe chains overflow
+#: MAX_PROBE long before half load). A copy kept up to date key by key
+#: grows by ``set``'s rule alone and can sit a doubling below the build of
+#: the same contents, at a capacity that depends on the order its keys
+#: arrived in; held to this density, it launches ``hintchain`` at the
+#: capacities the per-window rebuild it replaces gave the same contents
+SNAPSHOT_SLOTS_PER_ENTRY = 8
 
 
 # ---------------------------------------------------------------------------
@@ -196,6 +208,22 @@ class HashIndex:
                 self.used = self.live = len(entries)
                 return
 
+    def compact(self) -> None:
+        """Re-insert the live entries at the same capacity, dropping the
+        tombstones; doubles only where an entry then cannot land within
+        MAX_PROBE slots of home (as :meth:`set` would). The planner's
+        persistent hint snapshots call it where their tombstones alone
+        would make :meth:`set` double the table."""
+        live = self.par >= 0
+        entries = zip(self.par[live].tolist(), self.nam[live].tolist(),
+                      self.val[live].tolist())
+        self.par = np.full(self.cap, EMPTY, np.int32)
+        self.nam = np.zeros(self.cap, np.uint32)
+        self.val = np.full(self.cap, EMPTY, np.int32)
+        self.used = self.live = 0
+        for p, m, v in entries:
+            self.set(p, m, v)
+
     def arrays(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """The kernel-facing (parent, name_hash, value) triple — views,
         not copies; snapshot semantics come from the jit boundary."""
@@ -224,6 +252,24 @@ class HashIndex:
                 ambig.add(key)
                 idx.set(par, h, AMBIG)
         return idx
+
+
+def sync_bucket(index: HashIndex,
+                buckets: Dict[Tuple[int, int], Dict[Any, int]],
+                key: Tuple[int, int]) -> None:
+    """Set ``key``'s slot of ``index`` from what ``buckets`` holds under
+    it (``(parent_id, crc32(name)) -> {member: id}``, one member per
+    distinct name): none removes the key, one sets its id, two or more set
+    ``AMBIG``, which is what :meth:`HashIndex.from_entries` gives for the
+    same contents. An empty bucket is dropped from ``buckets``."""
+    d = buckets.get(key)
+    if not d:
+        buckets.pop(key, None)
+        index.remove(key[0], key[1])
+    elif len(d) == 1:
+        index.set(key[0], key[1], next(iter(d.values())))
+    else:
+        index.set(key[0], key[1], AMBIG)
 
 
 # ---------------------------------------------------------------------------
@@ -341,14 +387,7 @@ class ColumnarTable:
     # -- inode PK hash-index maintenance --------------------------------
     def _hash_sync(self, key: Tuple[int, int]) -> None:
         assert self.hindex is not None
-        d = self._hkey.get(key)
-        if not d:
-            self._hkey.pop(key, None)
-            self.hindex.remove(key[0], key[1])
-        elif len(d) == 1:
-            self.hindex.set(key[0], key[1], next(iter(d.values())))
-        else:
-            self.hindex.set(key[0], key[1], AMBIG)
+        sync_bucket(self.hindex, self._hkey, key)
 
     def _hash_add(self, pk: Tuple[Any, ...], row: Dict[str, Any]) -> None:
         key = (int(row["parent_id"]), name_hash32(row["name"]))
@@ -551,31 +590,155 @@ def _lower_one(ct: ColumnarTrace, i: int, wop: WorkloadOp, spec: Any,
     ct.target_ids.append(target_id)
 
 
-def _snapshot_resolver(cache: Any, fallback: Any
-                       ) -> Optional[Tuple[HashIndex, HashIndex]]:
-    """Hash-index snapshots of (client cache, merged namenode caches);
-    None when a view cannot be represented (unknown resolver shape)."""
-    if not hasattr(cache, "export_entries"):
+class HintSnapshot:
+    """A :class:`HashIndex` copy of a view of hint caches, kept across
+    windows and brought up to date from the caches' change journals.
+
+    The view is an ordered tuple of caches in which the first cache that
+    holds a key answers it (:class:`~repro.core.batch_planner.
+    MultiCacheResolver`'s rule; the client view is a tuple of one).
+    :meth:`refresh` applies only the keys the journals list, each read
+    back through ``peek``; a new tuple of caches (elastic membership,
+    failover) or a journal marked full (a ``clear``, or more changed keys
+    than the cache may hold) rebuilds the copy with
+    :meth:`HashIndex.from_entries`, as a fresh snapshot is built. The
+    caches are held weakly: a snapshot never keeps a cache alive.
+
+    ``_buckets`` keeps, per ``(parent_id, crc32(name))``, the names living
+    there and their ids, kept in the index by :func:`sync_bucket` as the
+    store keeps its own. The index keeps at least
+    ``SNAPSHOT_SLOTS_PER_ENTRY`` slots per live entry, doubling ahead of
+    ``set``'s own rule where needed. Removals leave tombstones; where they
+    alone would make the index double, it is compacted at the same
+    capacity instead. ``lock`` is held from a refresh until the answers
+    of the launch that reads the copy are back on the host.
+    """
+
+    def __init__(self) -> None:
+        self.index = HashIndex()
+        self.lock = threading.Lock()
+        self._buckets: Dict[Tuple[int, int], Dict[str, int]] = {}
+        self._members: Tuple["weakref.ref[Any]", ...] = ()
+        self._journals: List[Any] = []
+
+    def refresh(self, caches: Sequence[Any]) -> Tuple[int, int]:
+        """Bring the copy up to ``caches``' contents; returns (full
+        rebuilds, dirty keys applied) of this call."""
+        caches = tuple(caches)
+        if len(caches) != len(self._members) or any(
+                r() is not c for r, c in zip(self._members, caches)):
+            for r, journal in zip(self._members, self._journals):
+                gone = r()
+                if gone is not None:
+                    gone.detach_journal(journal)
+            self._members = tuple(weakref.ref(c) for c in caches)
+            self._journals = [c.attach_journal() for c in caches]
+            self._rebuild(caches)
+            return 1, 0
+        full = False
+        dirty: Set[Tuple[int, str]] = set()
+        for c, journal in zip(caches, self._journals):
+            f, keys = c.drain_journal(journal)
+            full = full or f
+            dirty |= keys
+        if full:
+            self._rebuild(caches)
+            return 1, 0
+        for par, name in dirty:
+            self._apply(caches, par, name)
+        return 0, len(dirty)
+
+    def _rebuild(self, caches: Tuple[Any, ...]) -> None:
+        merged: Dict[Tuple[int, str], int] = {}
+        for c in caches:
+            for par, name, iid in c.export_entries():
+                merged.setdefault((par, name), iid)
+        self.index = HashIndex.from_entries(
+            (par, name, iid) for (par, name), iid in merged.items())
+        while self.index.cap < SNAPSHOT_SLOTS_PER_ENTRY * self.index.live:
+            self.index._grow()
+        self._buckets = {}
+        for (par, name), iid in merged.items():
+            self._buckets.setdefault((par, name_hash32(name)), {})[name] = iid
+
+    def _apply(self, caches: Tuple[Any, ...], par: int, name: str) -> None:
+        v = None
+        for c in caches:
+            v = c.peek(par, name)
+            if v is not None:
+                break
+        key = (par, name_hash32(name))
+        names = self._buckets.get(key)
+        if v is not None:
+            if names is None:                # the key is new to the index
+                names = self._buckets[key] = {}
+                idx = self.index
+                while idx.cap < SNAPSHOT_SLOTS_PER_ENTRY * (idx.live + 1):
+                    idx._grow()
+                if 2 * (idx.used + 1) > idx.cap:
+                    idx.compact()
+            names[name] = v
+        elif names is None or names.pop(name, None) is None:
+            return
+        sync_bucket(self.index, self._buckets, key)
+
+
+#: the planner's persistent snapshots, kept beside the caches they copy
+#: and never keeping one alive: one per client cache, and one per tuple of
+#: namenode caches, shared by every planner over them (each member cache
+#: maps to its view, so a namenode cache carries one journal whatever the
+#: number of clients)
+_client_views: "weakref.WeakKeyDictionary[Any, HintSnapshot]" = \
+    weakref.WeakKeyDictionary()
+_namenode_views: "weakref.WeakKeyDictionary[Any, HintSnapshot]" = \
+    weakref.WeakKeyDictionary()
+_views_lock = threading.Lock()
+
+
+def snapshot_views(cache: Any, caches: Tuple[Any, ...]
+                   ) -> Tuple[HintSnapshot, HintSnapshot]:
+    """The persistent (client, merged namenode) snapshots for a client
+    cache and its fallback caches. A tuple of namenode caches takes over
+    the view of any of its members, which rebuilds at its next refresh
+    where the membership changed."""
+    with _views_lock:
+        cview = _client_views.get(cache)
+        if cview is None:
+            cview = _client_views[cache] = HintSnapshot()
+        nview = next((_namenode_views[c] for c in caches
+                      if c in _namenode_views), None)
+        if nview is None:
+            nview = HintSnapshot()
+        for c in caches:
+            _namenode_views[c] = nview
+    return cview, nview
+
+
+def _fallback_caches(fallback: Any) -> Optional[Tuple[Any, ...]]:
+    """The fallback resolver as an ordered tuple of journaled caches;
+    None when it is of another shape."""
+    if fallback is None:
+        return ()
+    caches = tuple(fallback.caches) if hasattr(fallback, "caches") \
+        else (fallback,)
+    if not all(hasattr(c, "attach_journal") for c in caches):
         return None
-    with span("planner.snapshot"):
-        cidx = HashIndex.from_entries(cache.export_entries())
-        if fallback is None:
-            fidx = HashIndex()
-        elif hasattr(fallback, "caches"):
-            # MultiCacheResolver precedence: first cache that knows a key wins
-            merged: Dict[Tuple[int, str], int] = {}
-            for c in fallback.caches:
-                if not hasattr(c, "export_entries"):
-                    return None
-                for par, name, iid in c.export_entries():
-                    merged.setdefault((par, name), iid)
-            fidx = HashIndex.from_entries(
-                (par, name, iid) for (par, name), iid in merged.items())
-        elif hasattr(fallback, "export_entries"):
-            fidx = HashIndex.from_entries(fallback.export_entries())
-        else:
-            return None
-    return cidx, fidx
+    return caches
+
+
+def _snapshot_resolver(cview: HintSnapshot, nview: HintSnapshot, cache: Any,
+                       caches: Tuple[Any, ...]
+                       ) -> Tuple[HashIndex, HashIndex, int, int]:
+    """Hash-index snapshots of (client cache, merged namenode caches),
+    brought up to date in place; with this call's full rebuilds and dirty
+    keys applied, both views together."""
+    with span("planner.snapshot") as sp:
+        r1, k1 = cview.refresh((cache,))
+        r2, k2 = nview.refresh(caches)
+        rebuilds, delta_keys = r1 + r2, k1 + k2
+        sp.set_metadata(snapshot_rebuilds=rebuilds,
+                        snapshot_delta_keys=delta_keys)
+    return cview.index, nview.index, rebuilds, delta_keys
 
 
 def lower_trace_fused(wops: Sequence[WorkloadOp], resolver: Any, *,
@@ -589,17 +752,24 @@ def lower_trace_fused(wops: Sequence[WorkloadOp], resolver: Any, *,
     the resolver's hit/fallback/miss telemetry is replayed from the
     kernel's per-depth source codes, and any op that touches a
     crc-collided (AMBIG) bucket is re-resolved through the exact per-probe
-    path.  Windows below ``min_batch`` total probes, resolvers that are
-    not a ``HintResolver`` shape, or an unavailable kernel stack all fall
-    back — the pure walk for the first two, the numpy oracle under the
-    ``_KernelProbe`` gate for the last (on the CPU only: where kernels run
-    compiled, a kernel failure propagates)."""
+    path.  The kernel walks the persistent snapshots of the client cache
+    and the merged namenode caches (:func:`snapshot_views`), brought up to
+    date from the caches' change journals; the resolver counts their full
+    rebuilds and dirty keys applied (``snapshot_rebuilds``,
+    ``snapshot_delta_keys``).  Windows below ``min_batch`` total probes,
+    resolvers that are not a ``HintResolver`` shape, or an unavailable
+    kernel stack all fall back — the pure walk for the first two, the
+    numpy oracle under the ``_KernelProbe`` gate for the last (on the CPU
+    only: where kernels run compiled, a kernel failure propagates)."""
     if min_batch is None:
         min_batch = HINTCHAIN_MIN_BATCH      # runtime lookup: patchable
     cache = getattr(resolver, "cache", None)
-    fallback = getattr(resolver, "fallback", None)
-    if cache is None or not all(hasattr(resolver, a) for a in
-                                ("hits", "fallback_hits", "misses")):
+    caches = _fallback_caches(getattr(resolver, "fallback", None))
+    if cache is None or not hasattr(cache, "attach_journal") \
+            or caches is None or not all(
+                hasattr(resolver, a) for a in
+                ("hits", "fallback_hits", "misses", "snapshot_rebuilds",
+                 "snapshot_delta_keys")):
         return lower_trace(wops, resolver, max_depth=max_depth), False
     n = len(wops)
     comps_of: List[Optional[List[str]]] = []
@@ -616,38 +786,43 @@ def lower_trace_fused(wops: Sequence[WorkloadOp], resolver: Any, *,
             total += len(comps)
     if total < max(2, min_batch):
         return lower_trace(wops, resolver, max_depth=max_depth), False
-    snap = _snapshot_resolver(cache, fallback)
-    if snap is None:
-        return lower_trace(wops, resolver, max_depth=max_depth), False
-    cidx, fidx = snap
     nam = np.zeros((n, max_depth), np.uint32)
     dep = np.zeros(n, np.int32)
     for i, comps in enumerate(comps_of):
         if comps:
             dep[i] = len(comps)
             nam[i, :len(comps)] = [name_hash32(c) for c in comps]
+    cview, nview = snapshot_views(cache, caches)
+    # the snapshots change in place at the next refresh: hold them until
+    # this launch's answers are read back to the host (every planner takes
+    # the client view's lock first, so two never wait on each other)
+    with cview.lock, nview.lock:
+        cidx, fidx, rebuilds, delta_keys = _snapshot_resolver(
+            cview, nview, cache, caches)
+        resolver.snapshot_rebuilds += rebuilds
+        resolver.snapshot_delta_keys += delta_keys
 
-    def kern() -> Tuple[np.ndarray, np.ndarray]:
-        from ..kernels.hintchain.ops import hintchain_resolve
-        return hintchain_resolve(cidx.arrays(), fidx.arrays(), nam, dep,
+        def kern() -> Tuple[np.ndarray, np.ndarray]:
+            from ..kernels.hintchain.ops import hintchain_resolve
+            return hintchain_resolve(cidx.arrays(), fidx.arrays(), nam, dep,
+                                     root_id=ROOT_ID)
+
+        def fallb() -> Tuple[np.ndarray, np.ndarray]:
+            from ..kernels.hintchain.ref import hintchain_ref
+            cp, cn, cv = cidx.arrays()
+            fp, fn, fv = fidx.arrays()
+            return hintchain_ref(cp, cn, cv, fp, fn, fv, nam, dep,
                                  root_id=ROOT_ID)
 
-    def fallb() -> Tuple[np.ndarray, np.ndarray]:
-        from ..kernels.hintchain.ref import hintchain_ref
-        cp, cn, cv = cidx.arrays()
-        fp, fn, fv = fidx.arrays()
-        return hintchain_ref(cp, cn, cv, fp, fn, fv, nam, dep,
-                             root_id=ROOT_ID)
-
-    try:
-        (childs, srcs), used = _with_phash_kernel(
-            kern, fallb, n_keys=total, min_batch=min_batch,
-            probe=_hintchain_probe)
-    except ImportError:
-        # even the numpy oracle failed (kernel package unimportable):
-        # the pure walk is always available, but the window is counted
-        _hintchain_probe.demotions += 1
-        return lower_trace(wops, resolver, max_depth=max_depth), False
+        try:
+            (childs, srcs), used = _with_phash_kernel(
+                kern, fallb, n_keys=total, min_batch=min_batch,
+                probe=_hintchain_probe)
+        except ImportError:
+            # even the numpy oracle failed (kernel package unimportable):
+            # the pure walk is always available, but the window is counted
+            _hintchain_probe.demotions += 1
+            return lower_trace(wops, resolver, max_depth=max_depth), False
 
     type_names = list(REGISTRY.names())
     type_of = {name: i for i, name in enumerate(type_names)}

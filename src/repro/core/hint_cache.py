@@ -22,8 +22,10 @@ hint staleness (rename/delete+recreate), the telemetry
 """
 from __future__ import annotations
 
+import threading
+import weakref
 from collections import OrderedDict
-from typing import Any, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Iterable, List, Optional, Sequence, Set, Tuple
 
 from .tables import ROOT_ID, split_path
 
@@ -47,8 +49,35 @@ def split_epoch_entries(hints: Iterable[Tuple[int, str, int]]
     return res, epochs
 
 
+class ChangeJournal:
+    """The keys whose mapping changed in one :class:`InodeHintCache` since
+    its consumer last drained it (:meth:`InodeHintCache.drain_journal`);
+    ``full`` after a :meth:`InodeHintCache.clear`, or once it would list
+    more keys than the cache may hold entries, in place of listing them:
+    the consumer then rebuilds its copy, which reads no more than applying
+    the keys would."""
+
+    __slots__ = ("keys", "full", "__weakref__")
+
+    def __init__(self) -> None:
+        self.keys: Set[Tuple[int, str]] = set()
+        self.full = False
+
+
 class InodeHintCache:
-    """LRU of (parent_id, name) -> inode_id."""
+    """LRU of (parent_id, name) -> inode_id.
+
+    A consumer that keeps a copy of the cache (the planner's hash-index
+    snapshots) attaches a :class:`ChangeJournal`; from then on every key
+    whose mapping changes is recorded there: a ``put`` of a new key or of
+    a different id, an ``invalidate``, an LRU eviction (a ``clear`` marks
+    the journal full). A cache nobody copies records nothing, and a
+    journal never lists more keys than the cache's ``capacity``: past
+    that it is marked full and stops recording until drained. The cache
+    holds its journals weakly, so a consumer that goes away stops the
+    recording. Journals are filled and drained under a lock of the
+    cache's own, so namenode threads may write while the planner
+    drains."""
 
     def __init__(self, capacity: int = 1_000_000):
         self.capacity = capacity
@@ -63,6 +92,41 @@ class InodeHintCache:
         #: epochs this cache never saw)
         self.seen_epoch = 0
         self.epoch_resets = 0
+        self._journals: "weakref.WeakSet[ChangeJournal]" = weakref.WeakSet()
+        self._journal_lock = threading.Lock()
+
+    # -- change journals --------------------------------------------------
+    def attach_journal(self) -> ChangeJournal:
+        """Start recording changed keys for a new consumer."""
+        j = ChangeJournal()
+        with self._journal_lock:
+            self._journals.add(j)
+        return j
+
+    def detach_journal(self, journal: ChangeJournal) -> None:
+        with self._journal_lock:
+            self._journals.discard(journal)
+
+    def drain_journal(self, journal: ChangeJournal
+                      ) -> Tuple[bool, Set[Tuple[int, str]]]:
+        """(full, keys changed) since the last drain; the journal starts
+        empty again."""
+        with self._journal_lock:
+            full, keys = journal.full, journal.keys
+            journal.full, journal.keys = False, set()
+        return full, keys
+
+    def _changed(self, key: Tuple[int, str]) -> None:
+        # called AFTER the mapping changed: a drain racing a writer then
+        # either sees the new mapping or leaves the key for the next drain
+        if self._journals:
+            with self._journal_lock:
+                for j in self._journals:
+                    if j.full:
+                        continue
+                    j.keys.add(key)
+                    if len(j.keys) > self.capacity:
+                        j.full, j.keys = True, set()
 
     def get(self, parent_id: int, name: str) -> Optional[int]:
         key = (parent_id, name)
@@ -81,8 +145,10 @@ class InodeHintCache:
             self.stale_overwrites += 1
         self._lru[key] = inode_id
         self._lru.move_to_end(key)
+        if prev != inode_id:
+            self._changed(key)
         if len(self._lru) > self.capacity:
-            self._lru.popitem(last=False)
+            self._changed(self._lru.popitem(last=False)[0])
 
     def peek(self, parent_id: int, name: str) -> Optional[int]:
         """Probe without touching LRU order or hit/miss counters — the
@@ -93,6 +159,7 @@ class InodeHintCache:
     def invalidate(self, parent_id: int, name: str) -> None:
         if self._lru.pop((parent_id, name), None) is not None:
             self.invalidations += 1
+            self._changed((parent_id, name))
 
     def invalidate_path(self, components: Sequence[str]) -> bool:
         """Client-side invalidation on a destructive op (rename/delete/
@@ -164,14 +231,22 @@ class InodeHintCache:
         hints, oldest-first so :meth:`absorb` on the receiver reproduces
         the LRU recency order. With ``limit``, only the NEWEST ``limit``
         entries — the warm working set a retiring namenode migrates to its
-        successors (and a joining one is pre-warmed with)."""
-        items = [(p, n, v) for (p, n), v in self._lru.items()]
+        successors (and a joining one is pre-warmed with). The entries
+        are copied in one step (``list`` over the items runs without
+        giving up the interpreter lock), so writers on other threads
+        cannot change the cache under the iteration."""
+        items = [(p, n, v) for (p, n), v in list(self._lru.items())]
         if limit is not None and len(items) > limit:
             items = items[-limit:]
         return items
 
     def clear(self) -> None:
         self._lru.clear()
+        if self._journals:
+            with self._journal_lock:
+                for j in self._journals:
+                    j.full = True
+                    j.keys = set()
 
     # deliberately NOT __len__: fs.py/namenode.py guard the optional cache
     # with `if self.cache:` (identity semantics), and a __len__ would make

@@ -9,7 +9,9 @@ spans are always on. Names are ``<layer>.<what>``; the layers are
 ``planner``, ``namenode``, ``kernel`` and ``client``:
 
 * ``planner.window`` (metadata ``window``: the window's sequence number),
-  with ``planner.lower``, ``planner.snapshot``, ``planner.validate`` and
+  with ``planner.lower``, ``planner.snapshot`` (metadata
+  ``snapshot_rebuilds`` and ``snapshot_delta_keys``: how its hint-cache
+  snapshots were brought up to date), ``planner.validate`` and
   ``planner.deal`` inside it; ``planner.absorb`` after the window ran;
 * ``namenode.batch``: one batch a pipeline hands to a namenode (metadata
   ``window`` where the batch was planned), with ``namenode.read_run``,
@@ -20,7 +22,8 @@ spans are always on. Names are ``<layer>.<what>``; the layers are
   the device, the device run and the copy back;
 * ``client.finalize``: the pipeline's cost roll-up after its last window.
 
-Metadata is for events that cross threads; the hot spans carry none.
+Metadata is for events that cross threads and for the snapshot's upkeep;
+the other hot spans carry none.
 """
 from __future__ import annotations
 

@@ -169,3 +169,111 @@ def test_profiled_run_writes_program_spans(planned_cluster, tmp_path):
             "client.finalize"} <= names
     assert {"kernel.hintchain", "kernel.pkval"} <= names
     assert len(windows) == 3               # 600 ops in windows of 256
+
+
+# ---------------------------------------------------------------------------
+# the hint-cache snapshots' upkeep: counters and the span's metadata
+# ---------------------------------------------------------------------------
+
+
+def test_snapshot_counters_count_rebuilds_and_dirty_keys():
+    from repro.core.batch_planner import HintResolver, MultiCacheResolver
+    from repro.core.columnar import lower_trace_fused
+    from repro.core.hint_cache import InodeHintCache
+    client = InodeHintCache(capacity=3)
+    nn0, nn1 = InodeHintCache(), InodeHintCache()
+    ns = SyntheticNamespace(NamespaceSpec(), n_dirs=4, files_per_dir=2)
+    wops = make_spotify_trace(ns, 40, seed=1)
+    r = HintResolver(client, MultiCacheResolver([nn0, nn1]))
+
+    def counts():
+        _, used = lower_trace_fused(wops, r, min_batch=2)
+        assert used
+        return r.snapshot_rebuilds, r.snapshot_delta_keys
+
+    assert counts() == (2, 0)              # one full build per view
+    client.put(1, "a", 5)
+    client.put(1, "b", 6)
+    client.put(1, "c", 7)
+    nn0.put(1, "a", 5)
+    nn0.put(1, "d", 8)
+    nn1.put(1, "a", 5)                     # dirty in two caches: one key
+    assert counts() == (2, 5)
+    client.put(1, "a", 5)                  # the same id again: nothing
+    nn1.put(1, "a", 5)
+    assert counts() == (2, 5)
+    client.put(1, "a", 9)                  # another id
+    client.invalidate(1, "b")
+    assert counts() == (2, 7)
+    client.put(1, "e", 10)
+    client.put(1, "f", 11)                 # evicts (1, "c")
+    assert counts() == (2, 10)
+    for name in "ghij":                    # more keys than the cache holds
+        client.put(1, name, 20)
+    assert counts() == (3, 10)             # a full journal rebuilds
+    client.clear()
+    assert counts() == (4, 10)             # so does a clear
+    r.fallback = MultiCacheResolver([nn1, nn0])
+    assert counts() == (5, 10)             # and a membership change
+    nn0.clear()
+    nn1.put(2, "k", 12)
+    assert counts() == (6, 10)             # the rebuild covers nn1's key
+
+
+def test_unobserved_cache_records_nothing():
+    from repro.core.hint_cache import InodeHintCache
+    cache = InodeHintCache(capacity=2)
+    for i in range(4):                     # puts and evictions
+        cache.put(1, f"n{i}", 10 + i)
+    cache.invalidate(1, "n3")
+    cache.clear()
+    assert len(cache._journals) == 0
+    j = cache.attach_journal()
+    cache.put(1, "x", 3)
+    cache.detach_journal(j)
+    cache.put(1, "y", 4)
+    cache.clear()
+    assert cache.drain_journal(j) == (False, {(1, "x")})
+    assert len(cache._journals) == 0
+
+
+def test_only_planned_runs_attach_journals(planned_cluster):
+    cluster, wops = planned_cluster
+    client = DFSClient(cluster)
+    client.mkdirs("/facade/dir")
+    client.stat("/facade/dir")
+    caches = [client.hint_cache] + [nn.ops.cache for nn in cluster.namenodes]
+    assert all(len(c._journals) == 0 for c in caches)
+    client.run_trace(wops, planned=True, window=256, adaptive=False)
+    assert all(len(c._journals) == 1 for c in caches)
+
+
+def test_snapshot_span_carries_the_counters(planned_cluster, tmp_path):
+    from jax.profiler import ProfileData
+    from repro.core.batch_planner import PlannedRequestPipeline
+    from repro.core.hint_cache import InodeHintCache
+    cluster, wops = planned_cluster
+    cache = InodeHintCache()
+    reports, meta = [], []
+    with jax.profiler.trace(str(tmp_path)):
+        for part in (wops[:300], wops[300:]):
+            pipe = PlannedRequestPipeline(cluster, window=150,
+                                          adaptive=False, client_cache=cache)
+            pipe.run(part)
+            reports.append(pipe.plan_report)
+    (pb,) = Path(tmp_path).rglob("*.xplane.pb")
+    for plane in ProfileData.from_file(str(pb)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == "planner.snapshot":
+                    st = dict(ev.stats)
+                    meta.append((st["snapshot_rebuilds"],
+                                 st["snapshot_delta_keys"]))
+    assert len(meta) == sum(r.hintchain_launches for r in reports) == 4
+    # the first window builds both views; later ones, pipelines included,
+    # apply what changed
+    assert meta[0] == (2, 0) and all(m[0] == 0 for m in meta[1:])
+    assert sum(m[1] for m in meta[1:]) > 0
+    assert [r.snapshot_rebuilds for r in reports] == [2, 0]
+    assert sum(r.snapshot_delta_keys for r in reports) \
+        == sum(m[1] for m in meta)
