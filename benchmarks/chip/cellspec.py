@@ -2,7 +2,10 @@
 
 A cell names its configuration and its traffic; a configuration's file
 is the one ``configs`` lists for it, a cell's traffic is
-``traffic/<name>.json`` (its rate, and the name of its mix), the mix it
+``traffic/<name>.json`` (its rate, the name of its mix, and optionally
+``scheduled``: fixed ops on directories of ``traffic: false`` namespace
+parts, each ``{"op", "path", "at_s", "phase": "warmup"|"window",
+"args"}``, see ``workgen.Scheduled``), the mix it
 names is ``mixes/<mix>.json``, and a metric is ``metrics/<name>.py``
 (loaded by path, since metric names hold dots). A metric split by the
 end-to-end metric it moves (``device.idle_share.steady``) falls back to
@@ -57,7 +60,8 @@ def find_cell(name: str, *, trace: bool, root: Path = ROOT,
 
 
 def load_traffic(name: str, here: Path = HERE) -> dict:
-    """``traffic/<name>.json`` with the rows of the mix it names."""
+    """``traffic/<name>.json`` with the rows of the mix it names; its
+    other keys (``scheduled`` among them) pass through as they are."""
     traffic = json.loads((here / "traffic" / f"{name}.json").read_text())
     mix = json.loads((here / "mixes" / f"{traffic['mix']}.json").read_text())
     return dict(traffic, mix_name=traffic["mix"], mix=mix["mix"])

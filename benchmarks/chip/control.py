@@ -82,12 +82,15 @@ def run_control(config: dict, traffic: dict, seed: int, seconds: float,
         windows.append(list(zip(batch, answers)))
         return answers
 
-    warm_due, ops = gen.schedule(warmup_seed, rate, warmup_s)
+    warm_due, ops = gen.schedule(warmup_seed, rate, warmup_s,
+                                 phase="warmup")
     drive(warm_due, serve, warmup_s, cap=cap)
     due, ops = gen.schedule(str(seed), rate, seconds,
-                            work_seed=WORK_SEED, block_s=BLOCK_S)
+                            work_seed=WORK_SEED, block_s=BLOCK_S,
+                            phase="window")
     win = drive(due, serve, seconds, cap=cap)
-    v = judge(plan, windows, RefView(svc.state), seed)
+    v = judge(plan, windows, RefView(svc.state), seed,
+              scheduled=[s.path for s in gen.scheduled])
     out = {k: val for k, (val, _) in v.numbers().items()}
     out.update(seed=seed, dispatched=win.dispatched, calls=len(win.calls),
                compared=v.compared_ops, uncompared=v.uncompared_reads,

@@ -45,6 +45,19 @@ def maybe_annotate(name: str, enabled: bool) -> Iterator[None]:
         yield
 
 
+def profile_options():
+    """The profiler's options for a traced window: device ops and the
+    ``TraceAnnotation`` spans (its host tracer), without its Python
+    tracer. That one, on by default, records every Python call and holds
+    it in memory until the trace stops -- some 340 B a call, tens of GiB
+    over a subtree op on a million-file directory -- and nothing here
+    reads it."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
+
+
 def load_events(log_dir: str) -> List[dict]:
     """Flatten the profile under ``log_dir`` to event records."""
     from jax.profiler import ProfileData

@@ -140,6 +140,12 @@ class NamespacePlan:
         raise IndexError("traffic file index out of range")
 
     # -- point queries (reference) -----------------------------------------
+    def part_of(self, comps: Sequence[str]) -> Optional[TreesPart]:
+        """The part whose trees hold the path; None for the root and for
+        names outside the loaded namespace."""
+        hit = self._tree_of.get(comps[0]) if comps else None
+        return hit[0] if hit is not None else None
+
     def lookup(self, comps: Sequence[str]) -> Optional[bool]:
         """None if the path is not in the loaded namespace, else is_dir."""
         if not comps:

@@ -60,6 +60,10 @@ class FSFail(Exception):
         self.name = name
 
 
+#: public methods of :class:`RefFS` that are not operations
+_NOT_OPS = frozenset({"apply", "fork", "get", "children", "has_op"})
+
+
 def split(path: str) -> Comps:
     return tuple(c for c in path.split("/") if c)
 
@@ -301,12 +305,18 @@ class RefFS:
         return (len(order), False)
 
     # -- dispatch ----------------------------------------------------------
+    @classmethod
+    def has_op(cls, op: str) -> bool:
+        """Whether ``op`` names an operation of the reference (and not one
+        of its storage, resolution or dispatch helpers)."""
+        return (not op.startswith("_") and op not in _NOT_OPS
+                and callable(getattr(cls, op, None)))
+
     def apply(self, op: str, path: str, path2: Optional[str],
               args: Dict[str, Any]) -> Tuple[Optional[str], Any]:
-        fn = getattr(self, op, None)
-        if fn is None or op.startswith("_") or op in ("apply", "fork", "get",
-                                                       "children"):
+        if not self.has_op(op):
             raise NotImplementedError(f"the reference has no op {op!r}")
+        fn = getattr(self, op)
         try:
             if op == "rename_file":
                 dst = path2 if path2 is not None else path + ".mv"

@@ -7,6 +7,10 @@ Set-up (timed from process start): find the cell's files by name, load
 the configuration's namespace into a 4-namenode columnar cluster, warm
 up at the cell's rate on a fixed seed stream, compile every kernel shape
 bucket a window can use, draw the window's schedule from ``--seed``.
+The traffic file's scheduled ops (``workgen.Scheduled``) go into the
+warm-up and the window at their offsets; each is logged with its
+due-to-return time, and readers find them in ``ctx.scheduled``, and
+the stats of every subtree op the window ran in ``ctx.subtree``.
 Then the open-loop window drives ``DFSClient.run_trace(planned=True,
 concurrent=False, adaptive=False)`` for ``--seconds``. After it, the
 plain reference judges every answer and the store (``verdict.py``).
@@ -38,7 +42,8 @@ ROOT = HERE.parents[1]
 sys.path.insert(0, str(HERE))
 
 from cellspec import Cell, find_cell, read_metrics  # noqa: E402
-from devtrace import load_events, maybe_annotate, reduce  # noqa: E402
+from devtrace import (  # noqa: E402
+    load_events, maybe_annotate, profile_options, reduce)
 from nsplan import NamespacePlan  # noqa: E402
 from openloop import drive  # noqa: E402
 from refmodel import ANSWER_ERRORS, normalize  # noqa: E402
@@ -114,6 +119,38 @@ def observe_planner(sink: list) -> Iterator[None]:
         cls.run = run
 
 
+#: the program's subtree operations (``SubtreeOps`` methods) observed
+SUBTREE_OPS = ("delete_subtree", "chmod_subtree", "chown_subtree")
+
+
+@contextlib.contextmanager
+def observe_subtree(sink: list) -> Iterator[None]:
+    """After every subtree op a namenode runs, collect its op, path, host
+    seconds and ``SubtreeOps.last_stats`` (waves, rows scanned, peak
+    frontier, phase-3 chunks), which the next subtree op resets."""
+    from repro.core.subtree import SubtreeOps
+    real = {name: getattr(SubtreeOps, name) for name in SUBTREE_OPS}
+
+    def observed(name, fn):
+        def op(self, path, *args, **kw):
+            t = time.perf_counter()
+            try:
+                return fn(self, path, *args, **kw)
+            finally:
+                stats = {k: v for k, v in self.last_stats.items()
+                         if k != "chunk_costs"}
+                sink.append(dict(stats, op=name, path=path,
+                                 seconds=time.perf_counter() - t))
+        return op
+    for name, fn in real.items():
+        setattr(SubtreeOps, name, observed(name, fn))
+    try:
+        yield
+    finally:
+        for name, fn in real.items():
+            setattr(SubtreeOps, name, fn)
+
+
 def counters(cluster, store, probes: dict, reports: list) -> dict:
     out = {
         "round_trips": sum(nn.agg_cost.round_trips
@@ -130,6 +167,7 @@ def counters(cluster, store, probes: dict, reports: list) -> dict:
     for fam, p in probes.items():
         out[f"{fam}.launches"] = p.launches
         out[f"{fam}.demotions"] = p.demotions
+        out[f"{fam}.h2d_bytes"] = p.h2d_bytes
     return out
 
 
@@ -160,6 +198,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     c_start = counters(cluster, store, probes, reports)
     ops: list = []
     windows: list = []          # [(wop, answer)] per call, for the verdict
+    subtree: list = []          # every subtree op's stats (observe_subtree)
 
     def serve(lo: int, hi: int) -> list:
         batch = ops[lo:hi]
@@ -178,8 +217,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         with maybe_annotate("wait_arrivals", trace):
             time.sleep(s)
 
-    with observe_planner(reports):
-        warm_due, ops = gen.schedule(WARMUP_SEED, rate, warmup_s)
+    with observe_planner(reports), observe_subtree(subtree):
+        warm_due, ops = gen.schedule(WARMUP_SEED, rate, warmup_s,
+                                     phase="warmup")
         warm = drive(warm_due, serve, warmup_s, cap=cap, sleep=wait)
         log(f"set-up: warm-up served {warm.dispatched} ops in "
             f"{len(warm.calls)} calls, backlog {warm.backlog}, at "
@@ -188,9 +228,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         log(f"set-up: kernel buckets warmed at "
             f"{time.perf_counter() - T_PROCESS:.3f} s")
         due, ops = gen.schedule(str(seed), rate, seconds,
-                                work_seed=WORK_SEED, block_s=BLOCK_S)
+                                work_seed=WORK_SEED, block_s=BLOCK_S,
+                                phase="window")
+        scheduled = [(k, s.op, s.path) for k, s in gen.placed]
         n_warm_windows = len(windows)
         c0 = counters(cluster, store, probes, reports)
+        n_subtree0 = len(subtree)
         traced0, compiled0 = compiles.traced, compiles.compiled
         trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") \
             if trace else ""
@@ -209,19 +252,22 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         log(f"set-up: {setup_s:.3f} s; window: {len(ops)} ops due over "
             f"{seconds} s at {rate} ops/s")
         if trace:
-            jax.profiler.start_trace(trace_dir)
+            jax.profiler.start_trace(trace_dir,
+                                     profiler_options=profile_options())
         try:
             with maybe_annotate("window", trace):
                 win = drive(due, serve, seconds, cap=cap, sleep=wait)
             jax.config.update("jax_log_compiles", False)
             c1 = counters(cluster, store, probes, reports)
+            win_subtree = subtree[n_subtree0:]
             traced = compiles.traced - traced0
             compiled = compiles.compiled - compiled0
             mem = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                       for d in devs)
             with maybe_annotate("reference_check", trace):
                 t_ref = time.perf_counter()
-                verdict = judge(plan, windows, StoreView(store), seed)
+                verdict = judge(plan, windows, StoreView(store), seed,
+                                scheduled=[s.path for s in gen.scheduled])
                 ref_s = time.perf_counter() - t_ref
         finally:
             if trace:
@@ -253,9 +299,20 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         f"{verdict.unserved} ops not served, {ref_s:.3f} s")
     for ex in verdict.examples:
         log(f"mismatch: {ex}")
+    for k, op, path in scheduled:
+        call = next((c for c in win.calls if c.lo <= k < c.hi), None)
+        if call is None:
+            log(f"scheduled {op} {path}: due {due[k]:.6f} s, not dispatched")
+            continue
+        ran = [s for s in win_subtree if s["op"] == op and s["path"] == path]
+        stats = "".join(f"; {key} {val}" for key, val in ran[-1].items()
+                        if key not in ("op", "path")) if ran else ""
+        log(f"scheduled {op} {path}: due {due[k]:.6f} s, returned after "
+            f"{call.end - due[k]:.6f} s, {answers[k][0] or 'ok'}{stats}")
 
     ctx = SimpleNamespace(window=win, served=served, setup_s=setup_s,
-                          counters=delta, trace=reduced, peak=peak)
+                          counters=delta, trace=reduced, peak=peak,
+                          scheduled=scheduled, subtree=win_subtree)
     numbers = verdict.numbers()
     numbers["kernel_demotions"] = (demotions, 0)
     device = {"platform": dev.platform, "kind": dev.device_kind,

@@ -6,11 +6,11 @@ program's own spans in the trace, and prints what they show.
 
 It is ``run_cell.py`` with a few of its names swapped
 (:func:`spans_kept`): the trace is loaded and reduced by ``spantrace``,
-the counters gain each kernel family's ``h2d_bytes``, the context the
-metric readers get is kept, and each ``DFSClient.run_trace`` call
-records, per op, how long the op's answer waited between the return of
-the batch that served it (``OpOutcome.done_s``) and the return of the
-call. After ``run_cell.py``'s own lines it prints
+the context the metric readers get is kept, and each
+``DFSClient.run_trace`` call records, per op, how long the op's answer
+waited between the return of the batch that served it
+(``OpOutcome.done_s``) and the return of the call. After
+``run_cell.py``'s own lines it prints
 ``idle attributed to program spans: X of Y s`` (the device idle time
 inside ``run_trace`` that program spans cover, of all of it), and its
 last stdout line is JSON: the readings of :func:`readings` and the nested
@@ -38,26 +38,19 @@ sys.path.insert(0, str(run_cell.ROOT / "src"))
 
 @contextlib.contextmanager
 def spans_kept() -> Iterator[SimpleNamespace]:
-    """Swap ``run_cell``'s trace loader and reducer, counters and metric
-    reading, and ``DFSClient.run_trace``, for versions that also keep
-    what :func:`readings` needs; yields where they keep it (``events``,
+    """Swap ``run_cell``'s trace loader and reducer and metric reading,
+    and ``DFSClient.run_trace``, for versions that also keep what
+    :func:`readings` needs; yields where they keep it (``events``,
     ``ctx``, ``calls``: per call, each op's held seconds or None)."""
     from repro.core import DFSClient
     seen = SimpleNamespace(events=None, ctx=None, calls=[])
     saved = {k: getattr(run_cell, k) for k in
-             ("load_events", "reduce", "counters", "read_metrics")}
+             ("load_events", "reduce", "read_metrics")}
     run_trace = DFSClient.run_trace
 
     def load_events(log_dir):
         seen.events = spantrace.load_events(log_dir)
         return seen.events
-
-    def counters(cluster, store, probes, reports):
-        out = saved["counters"](cluster, store, probes, reports)
-        for fam, p in probes.items():
-            if hasattr(p, "h2d_bytes"):
-                out[f"{fam}.h2d_bytes"] = p.h2d_bytes
-        return out
 
     def read_metrics(metrics, ctx, *a, **kw):
         seen.ctx = ctx
@@ -71,7 +64,7 @@ def spans_kept() -> Iterator[SimpleNamespace]:
         return st
 
     run_cell.load_events, run_cell.reduce = load_events, spantrace.reduce
-    run_cell.counters, run_cell.read_metrics = counters, read_metrics
+    run_cell.read_metrics = read_metrics
     DFSClient.run_trace = timed_run_trace
     try:
         yield seen

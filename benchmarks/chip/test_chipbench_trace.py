@@ -73,3 +73,38 @@ def test_kernel_name():
     assert kernel_name("jit_pkval(8156105431190824860)") == "pkval"
     assert kernel_name("jit_treeagg") == "treeagg"
     assert kernel_name("fusion.3") == "fusion.3"
+
+
+def _host_event_names(options, tmp_path):
+    """Names of the host events a trace records around 200 Python calls
+    inside a ``window`` span."""
+    import jax
+    from jax.profiler import ProfileData
+
+    def step(i):
+        return i + 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with jax.profiler.TraceAnnotation("window"):
+            s = 0
+            for _ in range(200):
+                s = step(s)
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(str(sorted(tmp_path.rglob("*.xplane.pb"))[-1]))
+    return [ev.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+@pytest.mark.parametrize("harness", [False, True])
+def test_traced_window_records_spans_without_python_calls(harness, tmp_path):
+    """The profiler's default options record one event per Python call
+    (``$file:line name``), which a long window cannot hold; the harness's
+    options record the spans alone."""
+    import jax
+    from devtrace import profile_options
+    opts = profile_options() if harness else jax.profiler.ProfileOptions()
+    names = _host_event_names(opts, tmp_path)
+    assert "window" in names
+    calls = [n for n in names if n.startswith("$") and n.endswith(" step")]
+    assert len(calls) == (0 if harness else 200)

@@ -17,6 +17,8 @@ what serializability guarantees and no more:
   ancestors), and a seeded sample of the rest of the namespace, reads
   back as the reference holds it; directories named get their listing
   compared; the store's inode and block counts equal the reference's.
+  Where the traffic schedules subtree ops, each scheduled directory and
+  a seeded sample of its loaded children are compared too.
 
 Ops the system did not serve (errors outside ``ANSWER_ERRORS``) are left
 out of the reference: a refused op must leave no trace, which the state
@@ -34,6 +36,8 @@ from refmodel import (ANSWER_ERRORS, READ_OPS, Comps, Node, RefFS, split)
 #: reads touched by more mutations of their window than this are counted
 #: as not compared (2^m subset states would be evaluated)
 MAX_SUBSET_MUTATIONS = 12
+#: loaded children of each scheduled directory compared after the run
+SCHEDULED_CHILDREN = 200
 #: deep-listing ops: a mutation anywhere below the path changes the answer
 _DEEP = frozenset({"du"})
 #: listing ops: a mutation of a direct child changes the answer
@@ -281,6 +285,21 @@ def sample_paths(plan: NamespacePlan, seed: int, n: int) -> Set[Comps]:
     return out
 
 
+def scheduled_sample(plan: NamespacePlan, dirs: Sequence[str], seed: int,
+                     n: int) -> Set[Comps]:
+    """Each scheduled directory and ``n`` of its loaded children, drawn
+    from ``seed``: rows a subtree op left behind or took wrongly show
+    here, beyond the inode count."""
+    rng = random.Random(f"{seed}/scheduled-sample")
+    out: Set[Comps] = set()
+    for d in dirs:
+        c = split(d)
+        kids = plan.children(c)
+        out.add(c)
+        out.update(c + (k,) for k in rng.sample(kids, min(n, len(kids))))
+    return out
+
+
 def check_state(ref: RefFS, view: Any, touched: Set[Comps],
                 sample: Set[Comps], v: Verdict,
                 max_listing: int = 100_000) -> None:
@@ -312,11 +331,15 @@ def check_state(ref: RefFS, view: Any, touched: Set[Comps],
 
 
 def judge(plan: NamespacePlan, windows: Sequence[Sequence[Tuple[Any, Any]]],
-          view: Any, seed: int, *, sample: int = 2000) -> Verdict:
-    """Run the whole comparison: answers window by window, then state."""
+          view: Any, seed: int, *, sample: int = 2000,
+          scheduled: Sequence[str] = ()) -> Verdict:
+    """Run the whole comparison: answers window by window, then state.
+    ``scheduled`` names the directories of the traffic's scheduled ops."""
     v = Verdict()
     ref = RefFS(plan)
     touched: Set[Comps] = set()
     check_windows(ref, windows, v, touched)
-    check_state(ref, view, touched, sample_paths(plan, seed, sample), v)
+    paths = sample_paths(plan, seed, sample)
+    paths |= scheduled_sample(plan, scheduled, seed, SCHEDULED_CHILDREN)
+    check_state(ref, view, touched, paths, v)
     return v

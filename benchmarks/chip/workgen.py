@@ -18,15 +18,23 @@ program can change the traffic it is measured on:
   makes, without its O(n) pass per draw over 10^6 files.
 
 Only the ``WorkloadOp`` record type comes from the program.
+
+A traffic file may also carry ``scheduled``: fixed operations on named
+directories of ``traffic: false`` namespace parts, each due at a fixed
+offset of the warm-up or the window (:class:`Scheduled`). They are put
+into the tape after it is drawn and dealt, so the tape is the same with
+or without them, and the seed's dealing never moves them.
 """
 from __future__ import annotations
 
 import bisect
 import itertools
 import random
-from typing import List, Optional, Sequence, Set, Tuple
+from dataclasses import dataclass, field
+from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 from nsplan import NamespacePlan
+from refmodel import RefFS, split
 
 #: argument pools (ops_registry.py lines 476-480)
 PERM_POOL = (0o644, 0o640, 0o755, 0o750, 0o700)
@@ -58,12 +66,58 @@ class ZipfSampler:
                              self.n - 1)
 
 
+#: the parts of a run a scheduled op can fall due in
+PHASES = ("warmup", "window")
+
+
+@dataclass(frozen=True)
+class Scheduled:
+    """One entry of a traffic file's ``scheduled`` list: ``op`` on the
+    directory ``path``, due ``at_s`` seconds into ``phase``, with ``args``
+    named as the mix's ops name theirs (``perm``, ``owner``, ...)."""
+    op: str
+    path: str
+    at_s: float
+    phase: str
+    args: Dict[str, Any] = field(default_factory=dict)
+
+
+def parse_scheduled(entry: dict, plan: NamespacePlan) -> Scheduled:
+    """``entry`` as a :class:`Scheduled`; ValueError, naming the entry,
+    unless it names an op of the plain reference on a directory of a
+    ``traffic: false`` part (one Table 1 never samples), in a known
+    phase."""
+    try:
+        s = Scheduled(**entry)
+    except TypeError as e:
+        raise ValueError(f"scheduled entry {entry!r}: {e}") from None
+    comps = split(s.path)
+    part = plan.part_of(comps)
+    if plan.lookup(comps) is not True:
+        why = "its path is not a directory of the namespace"
+    elif part is None or part.traffic:
+        why = "its path is not in a traffic: false namespace part"
+    elif not RefFS.has_op(s.op):
+        why = f"the plain reference has no op {s.op!r}"
+    elif s.phase not in PHASES:
+        why = f"its phase is not one of {PHASES}"
+    else:
+        return s
+    raise ValueError(f"scheduled entry {entry!r}: {why}")
+
+
 class TrafficGenerator:
-    """Stream of operations over a configuration's traffic namespace."""
+    """Stream of operations over a configuration's traffic namespace,
+    with a traffic file's scheduled operations beside it."""
 
     def __init__(self, plan: NamespacePlan, mix: Sequence[Sequence],
-                 zipf_s: float, popularity_seed: int):
+                 zipf_s: float, popularity_seed: int,
+                 scheduled: Sequence[dict] = ()):
         self.plan = plan
+        self.scheduled = [parse_scheduled(e, plan) for e in scheduled]
+        #: ``(index, entry)`` of each scheduled op the last
+        #: :meth:`schedule` call put into its tape
+        self.placed: List[Tuple[int, Scheduled]] = []
         self.ops = [m[0] for m in mix]
         self.cum = list(itertools.accumulate(float(m[1]) for m in mix))
         self.dir_frac = {m[0]: float(m[2]) for m in mix}
@@ -175,7 +229,8 @@ class TrafficGenerator:
         return self.build(name, on_dir)
 
     def schedule(self, seed: str, rate: float, seconds: float, *,
-                 work_seed: Optional[str] = None, block_s: float = 2.0
+                 work_seed: Optional[str] = None, block_s: float = 2.0,
+                 phase: Optional[str] = None
                  ) -> Tuple[List[float], list]:
         """Poisson arrivals at ``rate`` over ``[0, seconds)`` and one op
         per arrival. Without ``work_seed`` both are drawn from ``seed``
@@ -184,7 +239,11 @@ class TrafficGenerator:
         cut into blocks of ``block_s`` seconds, and ``seed`` only deals
         the blocks in another order: every seed then offers the same work
         (the same ops, the same gaps, the same total time) and runs
-        differ only in the order of its blocks."""
+        differ only in the order of its blocks.
+
+        Then each scheduled op of ``phase`` (none without one) goes in
+        at its ``at_s``, after every tape op due at or before it; their
+        positions are left in :attr:`placed`."""
         arrivals = random.Random(f"{work_seed or seed}/arrivals")
         self.rng = random.Random(f"{work_seed or seed}/ops")
         gaps: List[float] = []
@@ -209,6 +268,17 @@ class TrafficGenerator:
             gaps = [gaps[i] for i in idx]
             ops = [ops[i] for i in idx]
         due = list(itertools.accumulate(gaps))
+        self.placed = []
+        for s in sorted((s for s in self.scheduled if s.phase == phase),
+                        key=lambda s: s.at_s):
+            if not 0.0 <= s.at_s < seconds:
+                raise ValueError(f"scheduled {s.op} {s.path}: at_s {s.at_s} "
+                                 f"is outside the {phase} [0, {seconds})")
+            k = bisect.bisect_right(due, s.at_s)
+            due.insert(k, s.at_s)
+            ops.insert(k, workload_op(s.op, s.path, on_dir=True,
+                                      args=dict(s.args)))
+            self.placed.append((k, s))
         return due, ops
 
 
@@ -216,4 +286,5 @@ def make_generator(config: dict, traffic: dict,
                    plan: Optional[NamespacePlan] = None) -> TrafficGenerator:
     plan = plan or NamespacePlan(config["namespace"]["parts"])
     pop = config["popularity"]
-    return TrafficGenerator(plan, traffic["mix"], pop["zipf_s"], pop["seed"])
+    return TrafficGenerator(plan, traffic["mix"], pop["zipf_s"], pop["seed"],
+                            traffic.get("scheduled", ()))
