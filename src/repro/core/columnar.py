@@ -43,7 +43,8 @@ from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
 import numpy as np
 
 from .namenode import _KernelProbe, _with_phash_kernel
-from .store import MetadataStore
+from .store import (MetadataStore, index_row, scan_index_page,
+                    unindex_row)
 from .tables import ROOT_ID, TableSchema, pk_of
 from .ops_registry import REGISTRY
 from .spans import span
@@ -305,14 +306,15 @@ class ColumnarTable:
 
     Interface parity with ``Table`` (schema/n_partitions/parts/idx/
     n_rows/_pk_loc/partition_of/partition_of_pk/get/put/delete/
-    scan_index/scan_partition/scan_all) is what lets the transaction
-    engine, namenodes and ``dump_state`` run unchanged on either backend.
+    scan_index/scan_index_page/scan_partition/scan_all) is what lets the
+    transaction engine, namenodes and ``dump_state`` run unchanged on
+    either backend.
     """
 
     def __init__(self, schema: TableSchema, n_partitions: int):
         self.schema = schema
         self.n_partitions = n_partitions
-        self.idx: Dict[str, Dict[Any, Set[Tuple[Any, ...]]]] = {
+        self.idx: Dict[str, Dict[Any, Any]] = {
             c: {} for c in schema.indexes}
         self.n_rows = 0
         self._pk_loc: Optional[Dict[Tuple[Any, ...], int]] = (
@@ -427,13 +429,13 @@ class ColumnarTable:
         pk = pk_of(self.schema, row)
         p = self.partition_of(row[self.schema.partition_key])
         slot = self._slots.get(pk)
+        old = None
         if slot is None:
             slot = self._alloc()
             self._slots[pk] = slot
             self.n_rows += 1
         else:
             old = self._materialize(slot)
-            self._unindex(old, pk)
             if self.hindex is not None:
                 self._hash_remove(pk, old)
             if int(self.part_slots[slot]) != p:
@@ -446,7 +448,7 @@ class ColumnarTable:
         self._store_row(slot, row)
         if self._pk_loc is not None:
             self._pk_loc[pk] = p
-        self._index(row, pk)
+        self._index(row, pk, old)
         if self.hindex is not None:
             self._hash_add(pk, row)
 
@@ -466,17 +468,12 @@ class ColumnarTable:
         self.n_rows -= 1
         return True
 
-    def _index(self, row: Dict[str, Any], pk: Tuple[Any, ...]) -> None:
-        for c, ix in self.idx.items():
-            ix.setdefault(row[c], set()).add(pk)
+    def _index(self, row: Dict[str, Any], pk: Tuple[Any, ...],
+               old: Optional[Dict[str, Any]] = None) -> None:
+        index_row(self, row, pk, old)
 
     def _unindex(self, row: Dict[str, Any], pk: Tuple[Any, ...]) -> None:
-        for c, ix in self.idx.items():
-            s = ix.get(row[c])
-            if s is not None:
-                s.discard(pk)
-                if not s:
-                    del ix[row[c]]
+        unindex_row(self, row, pk)
 
     # -- scans -----------------------------------------------------------
     def scan_index(self, col: str, value: Any) -> List[Dict[str, Any]]:
@@ -487,6 +484,11 @@ class ColumnarTable:
             if r is not None:
                 out.append(r)
         return out
+
+    def scan_index_page(self, col: str, value: Any, after: Optional[int],
+                        limit: int
+                        ) -> Tuple[List[Dict[str, Any]], Optional[int]]:
+        return scan_index_page(self, col, value, after, limit)
 
     def scan_partition(self, part: int, pred: Callable[[Dict[str, Any]], bool]
                        ) -> List[Dict[str, Any]]:

@@ -16,8 +16,11 @@ spans are always on. Names are ``<layer>.<what>``; the layers are
 * ``namenode.batch``: one batch a pipeline hands to a namenode (metadata
   ``window`` where the batch was planned), with ``namenode.read_run``,
   ``namenode.write_run``, ``namenode.single`` (one op on the sequential
-  path) and ``namenode.piggyback`` inside it; ``namenode.subtree_wave``
-  around one advisory subtree wave;
+  path) and ``namenode.piggyback`` inside it; a subtree operation's
+  ``namenode.subtree_lock`` (phase 1), ``namenode.subtree_scan`` (one
+  phase-2 page of a directory's children, on the thread that scans it),
+  ``namenode.subtree_wave`` (one advisory wave launch) and
+  ``namenode.subtree_chunk`` (one phase-3 chunk commit);
 * ``kernel.<family>`` around one kernel launch: host staging, the copy to
   the device, the device run and the copy back;
 * ``client.finalize``: the pipeline's cost roll-up after its last window.

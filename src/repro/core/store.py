@@ -21,9 +21,11 @@ acquisition in the FS layer (§5, "Cyclic Deadlocks").
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 import zlib
+from bisect import bisect_left, bisect_right
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
@@ -273,14 +275,156 @@ def _hash_key(value: Any) -> int:
     return zlib.crc32(repr(value).encode())
 
 
+#: sequence numbers of paged index entries: one counter for every bucket,
+#: so a bucket emptied and filled again never hands out a number twice
+_PAGE_SEQ = itertools.count()
+#: guards every :class:`PagedBucket`: a key and its number go in together
+_PAGE_MU = threading.Lock()
+
+
+class PagedBucket:
+    """The primary keys of one partition-key value in its secondary index,
+    in insertion order, for paged scans (:meth:`Table.scan_index_page`).
+
+    ``pos`` maps each live key to its sequence number; ``keys`` and
+    ``seqs`` list the keys in the order they came in, with their numbers,
+    rising. A deleted key leaves a hole (None) in ``keys`` until holes
+    are half the list, when both lists drop them. A page resumes after the
+    number of the last key it returned, so deleting returned rows never
+    moves it, and a page costs one binary search and the entries it walks.
+    """
+
+    __slots__ = ("pos", "keys", "seqs", "holes")
+
+    def __init__(self) -> None:
+        self.pos: Dict[Tuple[Any, ...], int] = {}
+        self.keys: List[Optional[Tuple[Any, ...]]] = []
+        self.seqs: List[int] = []
+        self.holes = 0
+
+    def add(self, pk: Tuple[Any, ...]) -> None:
+        with _PAGE_MU:
+            if pk in self.pos:
+                return
+            seq = next(_PAGE_SEQ)
+            self.pos[pk] = seq
+            self.keys.append(pk)
+            self.seqs.append(seq)
+
+    def discard(self, pk: Tuple[Any, ...]) -> None:
+        with _PAGE_MU:
+            seq = self.pos.pop(pk, None)
+            if seq is None:
+                return
+            self.keys[bisect_left(self.seqs, seq)] = None
+            self.holes += 1
+            if 2 * self.holes > len(self.keys):
+                self.seqs = [s for k, s in zip(self.keys, self.seqs)
+                             if k is not None]
+                self.keys = [k for k in self.keys if k is not None]
+                self.holes = 0
+
+    def page(self, after: Optional[int], limit: int
+             ) -> Tuple[List[Tuple[Any, ...]], Optional[int]]:
+        """At most ``limit`` keys numbered after ``after`` (None: from the
+        first), and the cursor of the next page: the last key's number, or
+        None when no key follows."""
+        if limit < 1:
+            raise ValueError(f"page limit must be positive, got {limit}")
+        with _PAGE_MU:
+            keys, seqs = self.keys, self.seqs
+            n = len(keys)
+            i = 0 if after is None else bisect_right(seqs, after)
+            out: List[Tuple[Any, ...]] = []
+            last = after
+            while i < n and len(out) < limit:
+                k = keys[i]
+                if k is not None:
+                    out.append(k)
+                    last = seqs[i]
+                i += 1
+            while i < n and keys[i] is None:
+                i += 1
+            return out, (last if i < n else None)
+
+    def __iter__(self):
+        with _PAGE_MU:
+            return iter([k for k in self.keys if k is not None])
+
+    def __len__(self) -> int:
+        return len(self.pos)
+
+    def __eq__(self, other: object) -> bool:
+        if not isinstance(other, PagedBucket):
+            return NotImplemented
+        return self.pos.keys() == other.pos.keys()
+
+
+def index_row(table: Any, row: Dict[str, Any], pk: Tuple[Any, ...],
+              old: Optional[Dict[str, Any]] = None) -> None:
+    """Enter ``pk`` in every secondary index of ``table`` (a
+    :class:`Table` or a ``ColumnarTable``) under ``row``'s values, moving
+    it from ``old``'s where a value changed; an unchanged value keeps its
+    place in the order. The partition-key column's buckets are
+    :class:`PagedBucket` s, the others plain sets."""
+    paged = table.schema.partition_key
+    for c, ix in table.idx.items():
+        if old is not None:
+            if old[c] == row[c]:
+                continue
+            _unindex_value(ix, old[c], pk)
+        b = ix.get(row[c])
+        if b is None:
+            b = ix[row[c]] = PagedBucket() if c == paged else set()
+        b.add(pk)
+
+
+def _unindex_value(ix: Dict[Any, Any], value: Any,
+                   pk: Tuple[Any, ...]) -> None:
+    s = ix.get(value)
+    if s is not None:
+        s.discard(pk)
+        if not s:
+            del ix[value]
+
+
+def unindex_row(table: Any, row: Dict[str, Any],
+                pk: Tuple[Any, ...]) -> None:
+    for c, ix in table.idx.items():
+        _unindex_value(ix, row[c], pk)
+
+
+def scan_index_page(table: Any, col: str, value: Any, after: Optional[int],
+                    limit: int) -> Tuple[List[Dict[str, Any]], Optional[int]]:
+    """One page of ``table.scan_index(col, value)`` for the partition-key
+    column: at most ``limit`` rows after the cursor ``after`` (None: the
+    first page), in insertion order, and the next page's cursor (None
+    when no row follows). A full sweep returns each row that lived
+    through it exactly once, whatever was deleted between pages."""
+    if col != table.schema.partition_key:
+        raise ValueError(f"{table.schema.name}.{col} is not the partition "
+                         f"key: only its index scans page")
+    bucket = table.idx[col].get(value)
+    if bucket is None:
+        return [], None
+    pks, cursor = bucket.page(after, limit)
+    rows = []
+    for pk in pks:
+        r = table.get(pk)
+        if r is not None:
+            rows.append(r)
+    return rows, cursor
+
+
 class Table:
     def __init__(self, schema: TableSchema, n_partitions: int):
         self.schema = schema
         self.n_partitions = n_partitions
         self.parts: List[Dict[Tuple[Any, ...], Dict[str, Any]]] = [
             {} for _ in range(n_partitions)]
-        # secondary indexes: col -> value -> set of pks
-        self.idx: Dict[str, Dict[Any, Set[Tuple[Any, ...]]]] = {
+        # secondary indexes: col -> value -> pks (a PagedBucket for the
+        # partition-key column, else a set)
+        self.idx: Dict[str, Dict[Any, Any]] = {
             c: {} for c in schema.indexes}
         self.n_rows = 0
         # pk -> partition, maintained only for tables whose partition key
@@ -317,9 +461,7 @@ class Table:
         p = self.partition_of(row[self.schema.partition_key])
         part = self.parts[p]
         old = part.get(pk)
-        if old is not None:
-            self._unindex(old, pk)
-        elif self._pk_loc is not None:
+        if old is None and self._pk_loc is not None:
             # A partition-key UPDATE (e.g. concat re-owning block/replica
             # rows to the target file's inode id) moves the row between
             # shards — NDB performs an internal delete+insert.  Evict the
@@ -327,14 +469,12 @@ class Table:
             old_p = self._pk_loc.get(pk)
             if old_p is not None and old_p != p:
                 old = self.parts[old_p].pop(pk, None)
-                if old is not None:
-                    self._unindex(old, pk)
         if old is None:
             self.n_rows += 1
         part[pk] = row
         if self._pk_loc is not None:
             self._pk_loc[pk] = p
-        self._index(row, pk)
+        self._index(row, pk, old)
 
     def delete(self, pk: Tuple[Any, ...]) -> bool:
         p = self.partition_of_pk(pk)
@@ -347,17 +487,11 @@ class Table:
         self.n_rows -= 1
         return True
 
-    def _index(self, row, pk):
-        for c, ix in self.idx.items():
-            ix.setdefault(row[c], set()).add(pk)
+    def _index(self, row, pk, old=None):
+        index_row(self, row, pk, old)
 
     def _unindex(self, row, pk):
-        for c, ix in self.idx.items():
-            s = ix.get(row[c])
-            if s is not None:
-                s.discard(pk)
-                if not s:
-                    del ix[row[c]]
+        unindex_row(self, row, pk)
 
     # -- scans ----------------------------------------------------------
     def scan_index(self, col: str, value: Any) -> List[Dict[str, Any]]:
@@ -368,6 +502,11 @@ class Table:
             if r is not None:
                 out.append(r)
         return out
+
+    def scan_index_page(self, col: str, value: Any, after: Optional[int],
+                        limit: int
+                        ) -> Tuple[List[Dict[str, Any]], Optional[int]]:
+        return scan_index_page(self, col, value, after, limit)
 
     def scan_partition(self, part: int, pred: Callable[[Dict[str, Any]], bool]
                        ) -> List[Dict[str, Any]]:

@@ -15,13 +15,18 @@ transactions:
             on every descendant in the same total order inode ops use, via
             parallel partition-pruned index scans (children of one directory
             live on one shard, §4.2), reading only projections (inode ids)
-            for efficiency.  The default **incremental** mode streams the
-            waves — at most :attr:`SubtreeOps.wave_cap` directories are
-            expanded per scan round and file rows are flushed to phase 3 as
-            soon as a chunk fills, so memory stays bounded by one wave + one
-            chunk instead of the whole subtree.  The legacy mode
-            (``incremental=False``) still materializes the full
-            :class:`TreeNode` tree for callers that want it.
+            for efficiency.  Each directory is scanned in pages of at most
+            :attr:`SubtreeOps.batch_size` children
+            (:meth:`Transaction.ppis_page`), each page its own
+            take-and-release transaction, so no transaction holds a whole
+            million-entry directory.  The default **incremental** mode
+            streams the waves — at most :attr:`SubtreeOps.wave_cap`
+            directories are expanded per scan round and each page's file
+            rows are handed to phase 3 before the directory's next page is
+            scanned, so memory stays bounded by one wave's directories, one
+            page per directory and one chunk instead of the whole subtree.
+            The legacy mode (``incremental=False``) still materializes the
+            full :class:`TreeNode` tree for callers that want it.
   Phase 3 — execute: delete runs grouped chunk transactions **leaves first**
             so a namenode crash never orphans inodes (§6.2): files are
             deleted during the descent (they are always leaves), directories
@@ -52,7 +57,8 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, Iterator, List, Optional, Sequence,
+                    Tuple)
 
 from .fs import (FSError, FileAlreadyExists, FileNotFound, HopsFSOps,
                  OpResult, SubtreeLockedError, split_path)
@@ -183,7 +189,13 @@ class _BoundedWaitPool:
 
 
 def _empty_stats() -> Dict[str, Any]:
+    """``SubtreeOps.last_stats`` before an op: BFS waves, child rows
+    scanned, the most node rows held at once, phase-3 chunk commits,
+    phase-2 pages, inodes deleted, host seconds in phase-2 page scans
+    (``scan_s``) and in phase-3 chunk commits (``commit_s``), and each
+    chunk's cost."""
     return {"waves": 0, "scanned": 0, "peak_frontier": 0, "chunks": 0,
+            "pages": 0, "deleted": 0, "scan_s": 0.0, "commit_s": 0.0,
             "chunk_costs": []}
 
 
@@ -234,7 +246,9 @@ class SubtreeOps:
     # ------------------------------------------------------------------
     def _phase1_lock(self, path: str) -> Tuple[Dict[str, Any], OpCost]:
         comps = split_path(path)
-        with self.ops._begin(self.ops._hint_for(comps, parent=False)) as txn:
+        with span("namenode.subtree_lock"), \
+                self.ops._begin(self.ops._hint_for(comps, parent=False)) \
+                as txn:
             rp = self.ops._resolve(txn, comps, last_lock=EXCLUSIVE,
                                    path=path)
             root = rp.target
@@ -390,38 +404,63 @@ class SubtreeOps:
             self._executor = _BoundedWaitPool(self.parallelism)
         return self._executor
 
-    def _wave_scan(self, dir_ids: Sequence[int], cost: OpCost
-                   ) -> List[List[Dict[str, Any]]]:
-        """Take-and-release EXCLUSIVE child scans for one wave of
-        directories — one partition-pruned scan per directory (all
-        children co-located, §4.2), a thread pool across directories.
-        Returns the child-row lists aligned with ``dir_ids``."""
-        exp = self._fused_wave(dir_ids)
-
-        def scan_dir(did: int) -> List[Dict[str, Any]]:
+    def _scan_page(self, did: int, after: Optional[int], cost: OpCost
+                   ) -> Tuple[List[Dict[str, Any]], Optional[int]]:
+        """One take-and-release EXCLUSIVE page of ``did``'s children, in a
+        transaction of its own (projection: ids only — §6.1 "reduce the
+        overhead"): at most ``batch_size`` rows and the next page's cursor
+        (None: the directory is done). Without ADP the children are not
+        co-located, and the all-shard index scan takes them in one page."""
+        with span("namenode.subtree_scan"):
             with Transaction(self.store, partition_hint=("inode", did),
                              distribution_aware=self.ops.dat) as txn:
-                # take-and-release write locks on the children wave
-                # (projection: ids only — §6.1 "reduce the overhead")
                 if self.ops.adp:
-                    kids = txn.ppis("inode", "parent_id", did, EXCLUSIVE,
-                                    projection=("id", "parent_id", "name",
-                                                "is_dir"))
+                    kids, cursor = txn.ppis_page(
+                        "inode", "parent_id", did, EXCLUSIVE, after=after,
+                        limit=self.batch_size,
+                        projection=("id", "parent_id", "name", "is_dir"))
                 else:
                     kids = txn.index_scan("inode", "parent_id", did,
                                           EXCLUSIVE)
+                    cursor = None
                 cost.merge(txn.commit())
-            return kids
+        return kids, cursor
 
-        if len(dir_ids) > 1 and self.parallelism > 1:
-            kid_lists = list(self._pool().map(scan_dir, dir_ids))
-        else:
-            kid_lists = [scan_dir(d) for d in dir_ids]
-        if exp is not None \
-                and exp.n_children != sum(len(k) for k in kid_lists):
+    def _scan_rounds(self, dir_ids: Sequence[int], cost: OpCost
+                     ) -> Iterator[List[Tuple[int, List[Dict[str, Any]]]]]:
+        """Page through the children of one wave slice: yields one round
+        at a time, a list of ``(position in dir_ids, page of child rows)``
+        — the first page of every directory, then the next page of each
+        directory with rows left. A round's pages are scanned in parallel
+        on the pool (all children of a directory co-located, §4.2), and the
+        caller disposes of a round before the next is scanned."""
+        exp = self._fused_wave(dir_ids)
+        st = self.last_stats
+        open_dirs: List[Tuple[int, int, Optional[int]]] = [
+            (i, d, None) for i, d in enumerate(dir_ids)]
+        seen = 0
+        while open_dirs:
+            t0 = time.perf_counter()
+            if len(open_dirs) > 1 and self.parallelism > 1:
+                pages = self._pool().map(
+                    lambda o: self._scan_page(o[1], o[2], cost), open_dirs)
+            else:
+                pages = [self._scan_page(d, after, cost)
+                         for _, d, after in open_dirs]
+            st["scan_s"] += time.perf_counter() - t0
+            st["pages"] += len(open_dirs)
+            round_ = []
+            still_open = []
+            for (i, d, _), (kids, cursor) in zip(open_dirs, pages):
+                seen += len(kids)
+                round_.append((i, kids))
+                if cursor is not None:
+                    still_open.append((i, d, cursor))
+            open_dirs = still_open
+            yield round_
+        if exp is not None and exp.n_children != seen:
             # concurrent mutation between launch and scans: scans win
             self.treeagg_mismatches += 1
-        return kid_lists
 
     def _phase2_build_tree(self, root: Dict[str, Any], cost: OpCost
                            ) -> TreeNode:
@@ -432,19 +471,20 @@ class SubtreeOps:
         st = self.last_stats
         while frontier:
             st["waves"] += 1
-            kid_lists = self._wave_scan([n.inode_id for n in frontier], cost)
-            next_frontier: List[TreeNode] = []
-            for node, kids in zip(frontier, kid_lists):
-                st["scanned"] += len(kids)
-                node.children = [TreeNode(k["id"], k["parent_id"], k["name"],
-                                          k["is_dir"]) for k in kids]
-                next_frontier.extend(c for c in node.children if c.is_dir)
-            frontier = next_frontier
+            for round_ in self._scan_rounds(
+                    [n.inode_id for n in frontier], cost):
+                for i, kids in round_:
+                    st["scanned"] += len(kids)
+                    frontier[i].children.extend(
+                        TreeNode(k["id"], k["parent_id"], k["name"],
+                                 k["is_dir"]) for k in kids)
+            frontier = [c for node in frontier for c in node.children
+                        if c.is_dir]
         return tree
 
     def _phase2_quiesce(self, root: Dict[str, Any], cost: OpCost) -> int:
         """Streaming wave quiesce for root-only phase-3 ops: identical
-        take-and-release lock waves to the tree build, but nothing is
+        take-and-release lock pages to the tree build, but nothing is
         retained beyond the next frontier's directory ids (and each scan
         round expands at most ``wave_cap`` directories)."""
         st = self.last_stats
@@ -454,14 +494,15 @@ class SubtreeOps:
             st["waves"] += 1
             nxt: List[int] = []
             for s in range(0, len(wave), self.wave_cap):
-                kid_lists = self._wave_scan(wave[s:s + self.wave_cap], cost)
-                for kids in kid_lists:
-                    st["scanned"] += len(kids)
-                    total += len(kids)
-                    nxt.extend(k["id"] for k in kids if k["is_dir"])
-                resident = len(nxt) + (len(wave) - s)
-                if resident > st["peak_frontier"]:
-                    st["peak_frontier"] = resident
+                for round_ in self._scan_rounds(wave[s:s + self.wave_cap],
+                                                cost):
+                    for _, kids in round_:
+                        resident = len(nxt) + (len(wave) - s) + len(kids)
+                        if resident > st["peak_frontier"]:
+                            st["peak_frontier"] = resident
+                        st["scanned"] += len(kids)
+                        total += len(kids)
+                        nxt.extend(k["id"] for k in kids if k["is_dir"])
             wave = nxt
         return total
 
@@ -478,9 +519,10 @@ class SubtreeOps:
         """One phase-3 grouped transaction: every inode in the chunk
         shares the txn (the ``Namenode._write_group_txn`` discipline),
         anchored on the first node's parent partition."""
-        with Transaction(self.store,
-                         partition_hint=("inode", chunk[0][1]),
-                         distribution_aware=self.ops.dat) as txn:
+        with span("namenode.subtree_chunk"), \
+                Transaction(self.store,
+                            partition_hint=("inode", chunk[0][1]),
+                            distribution_aware=self.ops.dat) as txn:
             for iid, pid, name, is_dir in chunk:
                 if not is_dir:
                     related = self.ops._file_scan(
@@ -547,14 +589,18 @@ class SubtreeOps:
                     # already-deleted leaves are gone, rest still attached.
                     return True
                 before = cost.copy()
+                t0 = time.perf_counter()
                 cost.merge(self._commit_chunk(chunk))
+                st["commit_s"] += time.perf_counter() - t0
                 st["chunk_costs"].append(cost.diff(before).as_dict())
                 progress["batches"] += 1
                 progress["deleted"] += len(chunk)
                 if self.pace is not None:
                     self.pace()
             else:
+                t0 = time.perf_counter()
                 ccosts = list(self._pool().map(self._commit_chunk, group))
+                st["commit_s"] += time.perf_counter() - t0
                 for chunk, cc in zip(group, ccosts):
                     cost.merge(cc)
                     st["chunk_costs"].append(cc.as_dict())
@@ -577,6 +623,7 @@ class SubtreeOps:
             else:
                 crashed = self._delete_legacy(root, cost, progress)
             self.last_stats["chunks"] = progress["batches"]
+            self.last_stats["deleted"] = progress["deleted"]
             if crashed:
                 return OpResult({"deleted": progress["deleted"],
                                  "crashed": True}, cost)
@@ -608,6 +655,7 @@ class SubtreeOps:
         consistent smaller tree), directory rows are retained per level
         and deleted deepest level first, the root row last and alone."""
         st = self.last_stats
+        bs = self.batch_size
         rootnode: NodeRow = (root["id"], root["parent_id"], root["name"],
                              True)
         pending: List[NodeRow] = []
@@ -619,26 +667,29 @@ class SubtreeOps:
             next_wave: List[NodeRow] = []
             for s in range(0, len(wave), self.wave_cap):
                 sl = wave[s:s + self.wave_cap]
-                kid_lists = self._wave_scan([n[0] for n in sl], cost)
-                for kids in kid_lists:
-                    st["scanned"] += len(kids)
-                    resident = (retained + len(next_wave) + len(pending)
-                                + len(kids))
-                    if resident > st["peak_frontier"]:
-                        st["peak_frontier"] = resident
-                    for k in kids:
-                        node: NodeRow = (k["id"], k["parent_id"], k["name"],
-                                         k["is_dir"])
-                        if node[3]:
-                            next_wave.append(node)
-                        else:
-                            pending.append(node)
-                    while len(pending) >= self.batch_size:
-                        flush = pending[:self.batch_size]
-                        pending = pending[self.batch_size:]
-                        if self._exec_chunks(flush, cost, progress,
-                                             allow_parallel=True):
-                            return True
+                for round_ in self._scan_rounds([n[0] for n in sl], cost):
+                    for _, kids in round_:
+                        resident = (retained + len(next_wave) + len(pending)
+                                    + len(kids))
+                        if resident > st["peak_frontier"]:
+                            st["peak_frontier"] = resident
+                        st["scanned"] += len(kids)
+                        for k in kids:
+                            node: NodeRow = (k["id"], k["parent_id"],
+                                             k["name"], k["is_dir"])
+                            if node[3]:
+                                next_wave.append(node)
+                            else:
+                                pending.append(node)
+                        # hand every full chunk to phase 3 before the
+                        # directory's next page is scanned
+                        full = len(pending) - len(pending) % bs
+                        if full:
+                            flush = pending[:full]
+                            del pending[:full]
+                            if self._exec_chunks(flush, cost, progress,
+                                                 allow_parallel=True):
+                                return True
             if next_wave:
                 dir_levels.append(next_wave)
                 retained += len(next_wave)

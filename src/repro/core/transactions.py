@@ -187,6 +187,26 @@ class Transaction:
                                  match=lambda r: r.get(index_col) == value,
                                  index_key=(index_col, value))
 
+    def ppis_page(self, tname: str, index_col: str, value: Any,
+                  lock: str = READ_COMMITTED, *, after: Optional[int] = None,
+                  limit: int, projection: Optional[Sequence[str]] = None
+                  ) -> Tuple[List[Dict[str, Any]], Optional[int]]:
+        """One page of :meth:`ppis`: at most ``limit`` committed rows of
+        ``value``, resuming after the cursor ``after`` (None: the first
+        page), and the cursor of the next page (None: no rows left). Each
+        page is one PPIS round trip and locks only its own rows; deleting
+        rows a page returned never moves the next page (see
+        :class:`~repro.core.store.PagedBucket`). Rows this transaction
+        wrote are not overlaid (they have no place in the store's order),
+        so page in a transaction that wrote nothing under ``value``."""
+        t = self.store.table(tname)
+        part = t.partition_of(value)
+        self.store.check_available(part)
+        rows, cursor = t.scan_index_page(index_col, value, after, limit)
+        self.cost.ppis += 1
+        self._charge_rt([part])
+        return self._absorb_scan(tname, t, rows, lock, projection), cursor
+
     def index_scan(self, tname: str, index_col: str, value: Any,
                    lock: str = READ_COMMITTED) -> List[Dict[str, Any]]:
         """Index scan that cannot be pruned: hits every shard (cost IS)."""
