@@ -43,8 +43,8 @@ from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
 import numpy as np
 
 from .namenode import _KernelProbe, _with_phash_kernel
-from .store import (MetadataStore, index_row, scan_index_page,
-                    unindex_row)
+from .store import (MetadataStore, _hash_key, hash_partitions, index_row,
+                    scan_index_page, unindex_row, unindex_rows)
 from .tables import ROOT_ID, TableSchema, pk_of
 from .ops_registry import REGISTRY
 from .spans import span
@@ -306,9 +306,9 @@ class ColumnarTable:
 
     Interface parity with ``Table`` (schema/n_partitions/parts/idx/
     n_rows/_pk_loc/partition_of/partition_of_pk/get/put/delete/
-    scan_index/scan_index_page/scan_partition/scan_all) is what lets the
-    transaction engine, namenodes and ``dump_state`` run unchanged on
-    either backend.
+    scan_index/scan_index_page/scan_partition/scan_all, and the batch
+    partitions_of/delete_many) is what lets the transaction engine,
+    namenodes and ``dump_state`` run unchanged on either backend.
     """
 
     def __init__(self, schema: TableSchema, n_partitions: int):
@@ -405,8 +405,10 @@ class ColumnarTable:
 
     # -- placement (identical to Table) ---------------------------------
     def partition_of(self, partition_key_value: Any) -> int:
-        from .store import _hash_key
         return _hash_key(partition_key_value) % self.n_partitions
+
+    def partitions_of(self, values: Sequence[Any]) -> List[int]:
+        return hash_partitions(values, self.n_partitions)
 
     def partition_of_pk(self, pk: Tuple[Any, ...]) -> int:
         s = self.schema
@@ -467,6 +469,46 @@ class ColumnarTable:
         self._free.append(slot)
         self.n_rows -= 1
         return True
+
+    def delete_many(self, pks: Sequence[Tuple[Any, ...]]) -> int:
+        """:meth:`delete` of every key of ``pks``, in order, reading the
+        indexed values straight from the columns instead of materialising
+        each row: the indexes updated once per value, the hot arrays
+        cleared in one numpy step each, and the freed slots entering
+        ``_free`` in the order of ``pks``. Returns the number of rows
+        deleted."""
+        gone: List[Tuple[Any, ...]] = []
+        slots: List[int] = []
+        loc = self._pk_loc
+        for pk in pks:
+            slot = self._slots.pop(pk, None)
+            if loc is not None:
+                loc.pop(pk, None)
+            if slot is not None:
+                gone.append(pk)
+                slots.append(slot)
+        if not slots:
+            return 0
+        cols = self._cols
+        unindex_rows(self, gone, lambda c: [cols[c][i] for i in slots])
+        if self.hindex is not None:            # _hash_remove, per row
+            pars, hashes, hkey = cols["parent_id"], self._namehash, self._hkey
+            for pk, i in zip(gone, slots):
+                key = (int(pars[i]), int(hashes[i]))
+                d = hkey.get(key)
+                if d is not None:
+                    d.pop(pk, None)
+                    self._hash_sync(key)
+        for col in cols.values():
+            for i in slots:
+                col[i] = _MISSING
+        at = np.asarray(slots, np.int64)
+        for arr in self._hot.values():
+            arr[at] = -1
+        self.part_slots[at] = -1
+        self._free.extend(slots)
+        self.n_rows -= len(slots)
+        return len(slots)
 
     def _index(self, row: Dict[str, Any], pk: Tuple[Any, ...],
                old: Optional[Dict[str, Any]] = None) -> None:

@@ -218,6 +218,16 @@ class HopsFSOps:
                 out[tname] = txn.index_scan(tname, "inode_id", inode_id, lock)
         return out
 
+    def _file_scan_many(self, txn: Transaction, tables: Sequence[str],
+                        inode_ids: Sequence[int], lock: str = READ_COMMITTED
+                        ) -> Dict[str, List[List[Dict[str, Any]]]]:
+        """:meth:`_file_scan` of every file of ``inode_ids``: per table, the
+        rows of each file in order, with the cost of one scan per file."""
+        if self.adp:
+            return txn.ppis_many(tables, "inode_id", inode_ids, lock)
+        return {tname: [txn.index_scan(tname, "inode_id", i, lock)
+                        for i in inode_ids] for tname in tables}
+
     def _children(self, txn: Transaction, dir_id: int,
                   lock: str = READ_COMMITTED) -> List[Dict[str, Any]]:
         """Directory listing scan: partition-pruned because inodes are

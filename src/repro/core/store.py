@@ -30,6 +30,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
+import numpy as np
+
 from .tables import ALL_TABLES, TableSchema, pk_of
 
 # ---------------------------------------------------------------------------
@@ -76,13 +78,22 @@ EXCLUSIVE = "X"
 
 
 class _RowLock:
-    __slots__ = ("holders", "mode", "cond", "waiters")
+    __slots__ = ("holders", "mode", "_cond", "_cond_factory", "waiters")
 
     def __init__(self, cond_factory):
         self.holders: Set[int] = set()
         self.mode: Optional[str] = None
-        self.cond = cond_factory()
+        self._cond: Optional[threading.Condition] = None
+        self._cond_factory = cond_factory
         self.waiters = 0          # threads blocked in acquire() on this row
+
+    @property
+    def cond(self) -> threading.Condition:
+        """The row's condition, made on first use: only a waiter needs it,
+        and most row locks never see one."""
+        if self._cond is None:
+            self._cond = self._cond_factory()
+        return self._cond
 
 
 _LockKey = Tuple[str, Tuple[Any, ...]]
@@ -167,14 +178,79 @@ class LockManager:
         with self._held_mu:
             self._held.setdefault(txn_id, set()).add(key)
 
+    def acquire_many(self, txn_id: int, table: str,
+                     pks: Sequence[Tuple[Any, ...]], mode: str) -> None:
+        """:meth:`acquire` of every row of ``pks`` in ``table``: the same
+        grants, waits, timeouts and counters row by row, but each stripe's
+        mutex is taken once per call and a row's condition is made only
+        where a conflicting holder makes this call wait on it. On
+        :class:`LockTimeout` the rows granted so far stay held, as after
+        the per-row calls, for the transaction's abort to release."""
+        if mode == READ_COMMITTED:
+            return
+        by_stripe: Dict[int, List[_LockKey]] = {}
+        n = self.n_stripes
+        for pk in pks:
+            key = (table, pk)
+            by_stripe.setdefault(hash(key) % n, []).append(key)
+        granted: List[_LockKey] = []
+        try:
+            for s, keys in by_stripe.items():
+                mu = self._mus[s]
+                locks = self._locks[s]
+                factory = lambda mu=mu: threading.Condition(mu)  # noqa: E731
+                with mu:
+                    for key in keys:
+                        lk = locks.get(key)
+                        if lk is None:
+                            lk = locks[key] = _RowLock(factory)
+                        self.acquire_count += 1
+                        holders = lk.holders
+                        if holders and holders != {txn_id} and not (
+                                mode == SHARED and lk.mode == SHARED):
+                            self._wait(lk, locks, key, txn_id, mode)
+                        holders.add(txn_id)
+                        if mode == EXCLUSIVE:
+                            lk.mode = EXCLUSIVE
+                        elif lk.mode != EXCLUSIVE:
+                            lk.mode = SHARED
+                        granted.append(key)
+        finally:
+            if granted:
+                with self._held_mu:
+                    self._held.setdefault(txn_id, set()).update(granted)
+
+    def _wait(self, lk: _RowLock, locks: Dict[_LockKey, _RowLock],
+              key: _LockKey, txn_id: int, mode: str) -> None:
+        """Wait, with the row's stripe mutex held, until ``txn_id`` may
+        take ``lk`` in ``mode``: :meth:`acquire`'s conflict loop, counted
+        as one wait, bounded by the lock timeout."""
+        deadline = time.monotonic() + self.timeout
+        lk.waiters += 1
+        self.wait_count += 1
+        try:
+            while lk.holders and lk.holders != {txn_id} and not (
+                    mode == SHARED and lk.mode == SHARED):
+                remaining = deadline - time.monotonic()
+                if remaining <= 0 or not lk.cond.wait(remaining):
+                    raise LockTimeout(
+                        f"lock timeout on {key[0]}{key[1]} ({mode})")
+        except LockTimeout:
+            lk.waiters -= 1
+            if not lk.holders and not lk.waiters:
+                del locks[key]
+            raise
+        lk.waiters -= 1
+
     def release_all(self, txn_id: int) -> None:
         with self._held_mu:
             keys = self._held.pop(txn_id, None)
         if not keys:
             return
         by_stripe: Dict[int, List[_LockKey]] = {}
+        n = self.n_stripes
         for key in keys:
-            by_stripe.setdefault(self._stripe(key), []).append(key)
+            by_stripe.setdefault(hash(key) % n, []).append(key)
         for s, stripe_keys in by_stripe.items():
             with self._mus[s]:
                 locks = self._locks[s]
@@ -185,7 +261,8 @@ class LockManager:
                     lk.holders.discard(txn_id)
                     if not lk.holders:
                         lk.mode = None
-                    lk.cond.notify_all()
+                    if lk.waiters:
+                        lk.cond.notify_all()
                     # reclaim the entry only when nobody still waits on its
                     # condition — a waiter woken after the entry was dropped
                     # would otherwise mutate an orphaned lock object
@@ -269,10 +346,29 @@ def _hash_key(value: Any) -> int:
     """Deterministic partition hash (NDB uses MD5 of the partition key; we
     use crc32 of the repr — stable across runs, cheap, well-mixed for ids)."""
     if isinstance(value, int):
-        # avoid trivial modulo patterns on sequential ids
-        v = value * 0x9E3779B1 & 0xFFFFFFFF
-        return v ^ (v >> 16)
+        return _int_hash(value)
     return zlib.crc32(repr(value).encode())
+
+
+def _int_hash(v):
+    """The partition hash of an int, which avoids trivial modulo patterns
+    on sequential ids. Given a uint64 array (ints viewed as two's
+    complement), it hashes each entry to the same bits."""
+    v = v * 0x9E3779B1 & 0xFFFFFFFF
+    return v ^ (v >> 16)
+
+
+def hash_partitions(values: Sequence[Any], n_partitions: int) -> List[int]:
+    """``_hash_key(v) % n_partitions`` of each value; plain ints that fit
+    in 64 bits are hashed by :func:`_int_hash` in one numpy step."""
+    if values and all(type(v) is int for v in values):
+        try:
+            v = np.fromiter(values, np.int64, len(values)).view(np.uint64)
+        except OverflowError:
+            pass
+        else:
+            return (_int_hash(v) % np.uint64(n_partitions)).tolist()
+    return [_hash_key(v) % n_partitions for v in values]
 
 
 #: sequence numbers of paged index entries: one counter for every bucket,
@@ -322,6 +418,22 @@ class PagedBucket:
                 self.seqs = [s for k, s in zip(self.keys, self.seqs)
                              if k is not None]
                 self.keys = [k for k in self.keys if k is not None]
+                self.holes = 0
+
+    def discard_many(self, pks: Sequence[Tuple[Any, ...]]) -> None:
+        """:meth:`discard` of every key of ``pks`` under one hold of the
+        lock, with one compaction check at the end: the same live keys,
+        numbers and pages after it."""
+        with _PAGE_MU:
+            pos, keys, seqs = self.pos, self.keys, self.seqs
+            for pk in pks:
+                seq = pos.pop(pk, None)
+                if seq is not None:
+                    keys[bisect_left(seqs, seq)] = None
+                    self.holes += 1
+            if 2 * self.holes > len(keys):
+                self.seqs = [s for k, s in zip(keys, seqs) if k is not None]
+                self.keys = [k for k in keys if k is not None]
                 self.holes = 0
 
     def page(self, after: Optional[int], limit: int
@@ -394,6 +506,31 @@ def unindex_row(table: Any, row: Dict[str, Any],
         _unindex_value(ix, row[c], pk)
 
 
+def unindex_rows(table: Any, pks: Sequence[Tuple[Any, ...]],
+                 values: Callable[[str], Sequence[Any]]) -> None:
+    """:func:`unindex_row` of many rows at once: ``values(col)`` lists
+    each row's value of the indexed column ``col``, in the order of
+    ``pks``. A value's keys leave its bucket in one call, so a
+    :class:`PagedBucket` is locked and checked for compaction once."""
+    for c, ix in table.idx.items():
+        paged: Dict[Any, List[Tuple[Any, ...]]] = {}
+        for pk, v in zip(pks, values(c)):
+            b = ix.get(v)
+            if b is None:
+                continue
+            if type(b) is PagedBucket:
+                paged.setdefault(v, []).append(pk)
+                continue
+            b.discard(pk)
+            if not b:
+                del ix[v]
+        for v, vpks in paged.items():
+            b = ix[v]
+            b.discard_many(vpks)
+            if not b:
+                del ix[v]
+
+
 def scan_index_page(table: Any, col: str, value: Any, after: Optional[int],
                     limit: int) -> Tuple[List[Dict[str, Any]], Optional[int]]:
     """One page of ``table.scan_index(col, value)`` for the partition-key
@@ -438,6 +575,10 @@ class Table:
     # -- placement -----------------------------------------------------
     def partition_of(self, partition_key_value: Any) -> int:
         return _hash_key(partition_key_value) % self.n_partitions
+
+    def partitions_of(self, values: Sequence[Any]) -> List[int]:
+        """:meth:`partition_of` of each of ``values``."""
+        return hash_partitions(values, self.n_partitions)
 
     def partition_of_pk(self, pk: Tuple[Any, ...]) -> int:
         # partition key is always a PK column prefix or derivable from a row;
@@ -486,6 +627,24 @@ class Table:
         self._unindex(row, pk)
         self.n_rows -= 1
         return True
+
+    def delete_many(self, pks: Sequence[Tuple[Any, ...]]) -> int:
+        """:meth:`delete` of every key of ``pks``, in order, with the
+        secondary indexes updated once per indexed value. Returns the
+        number of rows deleted."""
+        gone_pks: List[Tuple[Any, ...]] = []
+        gone: List[Dict[str, Any]] = []
+        loc = self._pk_loc
+        for pk in pks:
+            row = self.parts[self.partition_of_pk(pk)].pop(pk, None)
+            if loc is not None:
+                loc.pop(pk, None)
+            if row is not None:
+                gone_pks.append(pk)
+                gone.append(row)
+        unindex_rows(self, gone_pks, lambda c: [r[c] for r in gone])
+        self.n_rows -= len(gone)
+        return len(gone)
 
     def _index(self, row, pk, old=None):
         index_row(self, row, pk, old)

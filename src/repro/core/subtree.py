@@ -71,6 +71,10 @@ from .transactions import Transaction
 #: per resident inode and nothing else
 NodeRow = Tuple[int, int, str, bool]
 
+#: the tables holding a file's rows beside its inode, which a phase-3
+#: delete removes with it
+FILE_TABLES = ("block", "replica", "ruc", "inv")
+
 
 @dataclass
 class TreeNode:
@@ -518,23 +522,27 @@ class SubtreeOps:
     def _commit_chunk(self, chunk: Sequence[NodeRow]) -> OpCost:
         """One phase-3 grouped transaction: every inode in the chunk
         shares the txn (the ``Namenode._write_group_txn`` discipline),
-        anchored on the first node's parent partition."""
+        anchored on the first node's parent partition. The chunk goes as
+        one batch: one scan call over the file tables for all its files,
+        then each table's rows locked and tombstoned in one call, the
+        files' rows before the inodes, and the commit's bulk store
+        deletes — the rows, locks and cost of one ``_file_scan`` and
+        ``delete`` per inode."""
         with span("namenode.subtree_chunk"), \
                 Transaction(self.store,
                             partition_hint=("inode", chunk[0][1]),
                             distribution_aware=self.ops.dat) as txn:
-            for iid, pid, name, is_dir in chunk:
-                if not is_dir:
-                    related = self.ops._file_scan(
-                        txn, ("block", "replica", "ruc", "inv"),
-                        iid, EXCLUSIVE)
-                    for tname, rws in related.items():
-                        schema = self.store.table(tname).schema
-                        for r in rws:
-                            txn.delete(tname,
-                                       tuple(r[c] for c in schema.pk))
-                txn.delete("inode", (pid, name))
-                if self.ops.cache:
+            files = [n[0] for n in chunk if not n[3]]
+            related = self.ops._file_scan_many(
+                txn, FILE_TABLES, files, EXCLUSIVE)
+            for tname, per_file in related.items():
+                pk = self.store.table(tname).schema.pk
+                txn.delete_many(tname, [tuple(r[c] for c in pk)
+                                        for rws in per_file for r in rws])
+            txn.delete_many("inode", [(pid, name)
+                                      for _, pid, name, _ in chunk])
+            if self.ops.cache:
+                for _, pid, name, _ in chunk:
                     self.ops.cache.invalidate(pid, name)
             return txn.commit()
 

@@ -23,7 +23,9 @@ group — this is what Fig 12/13's DAT ablation toggles.
 """
 from __future__ import annotations
 
+import itertools
 import time
+from collections import Counter
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 from .store import (EXCLUSIVE, READ_COMMITTED, SHARED, LockTimeout,
@@ -92,6 +94,19 @@ class Transaction:
             self.cost.local_rt += 1
         else:
             self.cost.remote_rt += 1
+
+    def _charge_rts(self, parts: Sequence[int]) -> None:
+        """One round trip per entry of ``parts``, each classified as
+        ``_charge_rt([part])`` classifies it."""
+        if self.coordinator_group is None:
+            self.cost.remote_rt += len(parts)
+            return
+        local = 0
+        for p, n in Counter(parts).items():
+            if self.store.group_of_partition(p).gid == self.coordinator_group:
+                local += n
+        self.cost.local_rt += local
+        self.cost.remote_rt += len(parts) - local
 
     def _row_op(self, n: int = 1) -> None:
         self.cost.rows_touched += n
@@ -186,6 +201,38 @@ class Transaction:
         return self._absorb_scan(tname, t, rows, lock, projection,
                                  match=lambda r: r.get(index_col) == value,
                                  index_key=(index_col, value))
+
+    def ppis_many(self, tnames: Sequence[str], index_col: str,
+                  values: Sequence[Any], lock: str = READ_COMMITTED
+                  ) -> Dict[str, List[List[Dict[str, Any]]]]:
+        """:meth:`ppis` of each of ``values`` on each table of ``tnames``,
+        in one call: per table, each value's rows in order, with the cost
+        of ``len(values)`` separate calls (one PPIS and one round trip per
+        value, a row op per row found, the read-your-writes overlay
+        included). The values' partitions are hashed once for the tables
+        of one partition count, and every one is checked for availability
+        before any table is scanned."""
+        tables = [self.store.table(n) for n in tnames]
+        parts_of: Dict[int, List[int]] = {}
+        for t in tables:
+            if t.n_partitions not in parts_of:
+                parts_of[t.n_partitions] = t.partitions_of(values)
+        for part in set().union(*parts_of.values()):
+            self.store.check_available(part)
+        out: Dict[str, List[List[Dict[str, Any]]]] = {}
+        for tname, t in zip(tnames, tables):
+            self.cost.ppis += len(values)
+            self._charge_rts(parts_of[t.n_partitions])
+            ix = t.idx.get(index_col, {})
+            # a value with no index entry scans to no rows, and absorbs
+            # none unless this transaction's own writes overlay it
+            out[tname] = [
+                self._absorb_scan(tname, t, t.scan_index(index_col, v), lock,
+                                  None,
+                                  match=lambda r, v=v: r.get(index_col) == v,
+                                  index_key=(index_col, v))
+                if v in ix or self.dirty else [] for v in values]
+        return out
 
     def ppis_page(self, tname: str, index_col: str, value: Any,
                   lock: str = READ_COMMITTED, *, after: Optional[int] = None,
@@ -352,6 +399,25 @@ class Transaction:
         self._mark_dirty(tname, pk)
         self._reindex_dirty(tname, self.store.table(tname), pk, None)
 
+    def delete_many(self, tname: str, pks: Sequence[Tuple[Any, ...]]
+                    ) -> None:
+        """:meth:`delete` of every key of ``pks``: all their EXCLUSIVE locks
+        are taken in one :meth:`LockManager.acquire_many` before any row is
+        tombstoned, then each leaves the tombstone and dirty entries its
+        own :meth:`delete` would."""
+        self.store.locks.acquire_many(self.txn_id, tname, pks, EXCLUSIVE)
+        t = self.store.table(tname)
+        cache, dirty, vals = self.cache, self.dirty, self._dirty_vals
+        order = self._dirty_order.setdefault(tname, [])
+        for pk in pks:
+            key = (tname, pk)
+            cache[key] = _TOMBSTONE
+            if key not in dirty:           # _mark_dirty, inline
+                dirty.add(key)
+                order.append(pk)
+            if key in vals:
+                self._reindex_dirty(tname, t, pk, None)
+
     # ------------------------------------------------------------------
     # UPDATE phase
     # ------------------------------------------------------------------
@@ -366,15 +432,23 @@ class Transaction:
             dirty = sorted(self.dirty)
             if dirty:
                 parts_touched = []
-                for tname, pk in dirty:
+                i = 0
+                while i < len(dirty):
+                    tname, pk = dirty[i]
                     t = self.store.table(tname)
                     v = self.cache[(tname, pk)]
-                    if v is _TOMBSTONE:
-                        t.delete(pk)
-                    else:
+                    if v is not _TOMBSTONE:
                         t.put(dict(v))
-                    self._row_op()
-                    parts_touched.append(t.partition_of_pk(pk))
+                        self._row_op()
+                        parts_touched.append(t.partition_of_pk(pk))
+                        i += 1
+                        continue
+                    run = self._tombstone_run(dirty, i)
+                    t.delete_many(run)
+                    self._row_op(len(run))
+                    parts_touched.extend(t.partition_of_pk(rpk)
+                                         for rpk in run)
+                    i += len(run)
                 if len(dirty) <= 8:
                     self.cost.pk_w += len(dirty)
                     for p in parts_touched:
@@ -388,6 +462,19 @@ class Transaction:
             return self.cost
         finally:
             self._finish()
+
+    def _tombstone_run(self, dirty: List[Tuple[str, Tuple[Any, ...]]],
+                       i: int) -> List[Tuple[Any, ...]]:
+        """The keys of the run of one table's tombstones, consecutive in
+        the commit's sorted order, that starts at ``dirty[i]``."""
+        tname = dirty[i][0]
+        run: List[Tuple[Any, ...]] = []
+        cache = self.cache
+        for tn, pk in itertools.islice(dirty, i, None):
+            if tn != tname or cache[(tn, pk)] is not _TOMBSTONE:
+                break
+            run.append(pk)
+        return run
 
     def abort(self) -> None:
         if not self._done:

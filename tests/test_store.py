@@ -3,7 +3,7 @@ import pytest
 
 from repro.core import (EXCLUSIVE, READ_COMMITTED, SHARED, MetadataStore,
                         NodeGroupDown, Transaction, format_fs)
-from repro.core.store import LockManager, _hash_key
+from repro.core.store import LockManager, _hash_key, hash_partitions
 from repro.core.tables import make_inode
 
 
@@ -20,6 +20,20 @@ def test_partitioning_is_deterministic(store):
     # children co-located: same parent id -> same partition (paper §4.2)
     parts = {t.partition_of(7) for _ in range(10)}
     assert len(parts) == 1
+
+
+@pytest.mark.parametrize("n_partitions", [1, 7, 64])
+@pytest.mark.parametrize("values", [
+    [0, 1, 2, 3, 1000, 123456789],
+    [-1, -2, -(2 ** 31), -(2 ** 63), -(2 ** 63) + 1],
+    [2 ** 32 - 1, 2 ** 32, 2 ** 32 + 1, 2 ** 31, 2 ** 31 - 1],
+    [2 ** 63 - 1, 2 ** 63 - 2, 2 ** 62 + 12345],
+    [2 ** 63, 2 ** 64 + 5, 1],                 # past int64: per value
+    [7, "a", (1, "b"), True, 7.5],             # not all ints: per value
+])
+def test_hash_partitions_is_hash_key_per_value(values, n_partitions):
+    assert hash_partitions(values, n_partitions) == \
+        [_hash_key(v) % n_partitions for v in values]
 
 
 def test_children_on_same_shard(store):
